@@ -125,7 +125,7 @@ def bench_deltas(smoke: bool, sharded: bool = False):
     rng = np.random.default_rng(0)
     task = pipeline.prepare("rgat", "dblp", scale=scale, max_degree=None, seed=0)
     mesh = (
-        jax.sharding.Mesh(np.array(jax.devices()[:8]), ("data",))
+        jax.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[:8]), ("data",)))
         if sharded
         else contextlib.nullcontext()
     )

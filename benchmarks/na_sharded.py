@@ -56,7 +56,7 @@ WAYS = (1, 2, 4, 8)
 
 
 def _mesh(n):
-    return jax.sharding.Mesh(np.array(jax.devices()[:n]), ("data",))
+    return jax.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[:n]), ("data",)))
 
 
 def _na_stage(task, rng):

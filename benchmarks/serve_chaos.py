@@ -322,7 +322,7 @@ def bench_sharded(model: str, scale: float):
     each flow's own sharded full forward."""
     task = pipeline.prepare(model, "imdb", scale=scale, max_degree=32, seed=0)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]), ("data",))
-    with mesh:
+    with jax.set_mesh(mesh):
         sess = task.compile(FlowConfig("fused_kernel", prune_k=PRUNE_K))
         fb_sess = task.compile(
             FlowConfig("fused", prune_k=PRUNE_K)

@@ -149,7 +149,7 @@ def bench_sharded(model: str, scale: float, n_queries: int):
     cfg = FlowConfig("fused_kernel", prune_k=PRUNE_K)
     task = pipeline.prepare(model, "imdb", scale=scale, max_degree=64, seed=0)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]), ("data",))
-    with mesh:
+    with jax.set_mesh(mesh):
         sess = task.compile(cfg)
         info = sess.mesh_info
         assert info is not None and info[2] == 8, "no ambient 8-way mesh"

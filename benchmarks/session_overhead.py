@@ -157,7 +157,7 @@ def bench_sharded(model: str, scale: float):
         jax.jit(lambda p: task.model.apply(p, task.batch, cfg))(params)
     )
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]), ("data",))
-    with mesh:
+    with jax.set_mesh(mesh):
         sess = task.compile(cfg)
         assert sess.mesh_info is not None and sess.mesh_info[2] == 8, (
             "session did not bind the ambient 8-way mesh"

@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import flows, pipeline
 from repro.core.flows import FlowConfig
 from repro.serve import (
@@ -52,6 +53,7 @@ def _report(name, stats, wall=None):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="rgat",
                     choices=("han", "rgat", "simple_hgn"))

@@ -4,7 +4,8 @@
   * ``staged``        — traditional baseline (no pruning)
   * ``staged_pruned`` — separate pruning pass then staged NA (Fig. 3 setup)
   * ``fused``         — ADE operation-fusion flow (scan-tiled jnp)
-  * ``fused_kernel``  — ADE flow via the Pallas kernel (interpret-mode on CPU)
+  * ``fused_kernel``  — ADE flow via the Pallas kernel (Mosaic on TPU,
+                        interpret mode on CPU)
 
 Two entry points: ``run_aggregate`` operates on raw padded-CSC arrays;
 ``run_aggregate_graph`` accepts either a flat ``SemanticGraph`` or a
@@ -25,7 +26,7 @@ slot copy) or via the static per-bucket routing in ``run_aggregate``.
 bucket path (eager Python loop + per-bucket scatters) for benchmarks and
 golden parity tests; see ``benchmarks/na_dispatch.py``.
 
-MULTI-DEVICE: when a concrete mesh with a ``bucket_tiles`` rule axis (the
+MULTI-DEVICE: when a mesh with a ``bucket_tiles`` rule axis (the
 ``("data",)`` inference mesh) is ambient, ``fused_kernel`` bucketed NA
 shards transparently — the graph's ``ShardedBucketLayout`` partitions the
 grouped tile stack by target row blocks, ``shard_map`` runs ONE kernel

@@ -139,13 +139,9 @@ class InferenceSession:
         output sharding, so gather programs lowered against it accept the
         executable's committed output directly (mesh or not)."""
         sds = jax.eval_shape(fn, params)
-        try:
-            sharding = self._executable.output_shardings
-        except Exception:  # pragma: no cover - old-jax fallback
-            sharding = None
-        if sharding is None:
-            return sds
-        return jax.ShapeDtypeStruct(sds.shape, sds.dtype, sharding=sharding)
+        return jax.ShapeDtypeStruct(
+            sds.shape, sds.dtype, sharding=self._executable.output_shardings
+        )
 
     def compile_query(self, capacity: int):
         """The AOT gather program serving ``(capacity,)`` query blocks:

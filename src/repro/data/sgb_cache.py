@@ -60,13 +60,10 @@ def default_cache_dir() -> Optional[Path]:
 
 def _tile_constants() -> Tuple[int, int]:
     """The grouped kernel's tile shape — what the sharded dispatch keys its
-    layout cache on. Falls back to hetgraph's generic defaults when the
-    kernel stack (jax) isn't importable."""
-    try:
-        from repro.kernels.fused_prune_aggregate.kernel import T_TILE, W_TILE
-        return int(T_TILE), int(W_TILE)
-    except Exception:
-        return 8, 8
+    layout cache on."""
+    from repro.kernels.fused_prune_aggregate.kernel import T_TILE, W_TILE
+
+    return int(T_TILE), int(W_TILE)
 
 
 def graph_fingerprint(g: HetGraph) -> str:

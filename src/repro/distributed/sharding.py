@@ -11,7 +11,7 @@ An axis is silently dropped when the dim size does not divide the mesh axis
 replication there and shard a different dim instead.
 
 Graph workloads add three logical axes (``bucket_tiles``, ``targets``,
-``ntype_feat``, see DEFAULT_RULES) and the concrete-mesh helpers
+``ntype_feat``, see DEFAULT_RULES) and the mesh helpers
 (``ambient_mesh``/``graph_mesh``/``shard_map_call``/``replicate``) that the
 sharded grouped-NA inference path in ``repro.core.flows`` binds to: when a
 mesh with a ``bucket_tiles`` rule axis is ambient, bucketed NA shard_maps
@@ -26,11 +26,6 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # private fallback for jax 0.4.x; absent/moved on other releases
-    from jax._src import mesh as _mesh_internal
-except ImportError:  # pragma: no cover - depends on installed jax
-    _mesh_internal = None
 
 # logical axis -> preferred mesh axes (in order; tuple = shard over several)
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
@@ -82,77 +77,32 @@ def axis_rules(overrides: Dict[str, Tuple[str, ...]]):
 
 
 def _axes_of(m) -> Dict[str, int]:
-    if m is None:
+    if m is None or m.empty:
         return {}
-    names = getattr(m, "axis_names", ()) or ()
-    if not names:
-        return {}
-    sizes = getattr(m, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(names, sizes))
-    shape = getattr(m, "shape", None)  # Mesh.shape: OrderedDict name -> size
-    return dict(shape) if shape is not None else {}
+    return dict(zip(m.axis_names, m.axis_sizes))
 
 
 def _mesh_axes() -> Dict[str, int]:
-    # jax >= 0.5 exposes the ambient abstract mesh publicly; 0.4.x keeps it
-    # in jax._src.mesh and sets the physical mesh via the Mesh context
-    # manager (thread_resources). Support both.
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        fn = getattr(_mesh_internal, "get_abstract_mesh", None)
-    if fn is not None:
-        try:
-            axes = _axes_of(fn())
-        except Exception:
-            axes = {}
-        if axes:
-            return axes
-    env = getattr(_mesh_internal, "thread_resources", None)
-    if env is not None:
-        return _axes_of(env.env.physical_mesh)
-    return {}
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient for ``constrain``.
-
-    ``jax.set_mesh`` where available (jax >= 0.5); otherwise the classic
-    ``with mesh:`` context (thread_resources), which 0.4.x pjit resolves.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    # the ambient mesh, set by ``jax.set_mesh``; abstract inside a trace
+    return _axes_of(jax.sharding.get_abstract_mesh())
 
 
 def ambient_mesh():
-    """The ambient CONCRETE mesh (``jax.sharding.Mesh``), or ``None``.
+    """The mesh given to ``jax.set_mesh``, or ``None`` without one.
 
-    ``_mesh_axes`` is enough for PartitionSpec resolution, but ``shard_map``
-    needs actual devices. Compat shims, newest API first: ``get_mesh`` /
-    ``get_concrete_mesh`` (jax >= 0.5 ``jax.set_mesh`` world — the abstract
-    mesh from ``get_abstract_mesh`` has no devices and is never returned
-    here), then 0.4.x ``thread_resources`` (the ``with mesh:`` context).
+    Outside a trace this is the concrete ``jax.sharding.Mesh``, whose
+    devices an eager ``device_put`` needs. Inside a trace JAX exposes only
+    the abstract mesh, which ``shard_map`` and ``NamedSharding`` accept
+    just the same.
     """
-    for getter in (
-        getattr(jax.sharding, "get_mesh", None),
-        getattr(_mesh_internal, "get_concrete_mesh", None),
-    ):
-        if getter is None:
-            continue
-        try:
-            m = getter()
-        except Exception:  # pragma: no cover - depends on installed jax
-            continue
-        if m is not None and getattr(m, "devices", None) is not None:
-            if not getattr(m, "empty", False):
-                return m
-    env = getattr(_mesh_internal, "thread_resources", None)
-    if env is not None:
-        m = env.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    return None
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract.empty:
+        return None
+    try:
+        mesh = jax.sharding.get_mesh()
+    except ValueError:  # inside a trace
+        return abstract
+    return abstract if mesh.empty else mesh
 
 
 def graph_shard_axis(mesh=None) -> Optional[str]:
@@ -167,7 +117,7 @@ def graph_shard_axis(mesh=None) -> Optional[str]:
 
 def graph_mesh():
     """``(mesh, axis_name, n_shards)`` for sharded grouped NA, or ``None``
-    when no concrete mesh with a ``bucket_tiles`` rule axis is ambient —
+    when no mesh with a ``bucket_tiles`` rule axis is ambient —
     the no-mesh no-op contract of the transparent sharding path."""
     mesh = ambient_mesh()
     if mesh is None:
@@ -178,32 +128,13 @@ def graph_mesh():
     return mesh, ax, _axes_of(mesh)[ax]
 
 
-def shard_map_fn():
-    """``shard_map`` across jax versions: top-level ``jax.shard_map``
-    (>= 0.6) or ``jax.experimental.shard_map.shard_map`` (0.4.x)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
-
-    return shard_map
-
-
 def shard_map_call(body, mesh, in_specs, out_specs):
-    """Wrap ``body`` in shard_map with replication checking off (the pallas
-    calls inside the NA body don't carry replication info). The keyword
-    spells ``check_rep`` on 0.4.x/0.5 and ``check_vma`` on newer jax."""
-    sm = shard_map_fn()
-    try:
-        return sm(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:  # pragma: no cover - depends on installed jax
-        return sm(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
+    """Wrap ``body`` in ``jax.shard_map`` with replication checking off (the
+    pallas calls inside the NA body don't carry replication info)."""
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def replicate(x: jax.Array, mesh) -> jax.Array:
@@ -314,7 +245,7 @@ def param_sharding_tree(params_shape, mesh, fsdp: bool = False):
         names = param_logical_axes(_path_str(path), len(leaf.shape), fsdp)
         spec = []
         used = set()
-        msizes = dict(zip(mesh.axis_names, mesh.shape.values()) if hasattr(mesh.shape, 'values') else zip(mesh.axis_names, mesh.axis_sizes))
+        msizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
         for name, dim in zip(names, leaf.shape):
             axes, size = [], 1
             for ax in rules.get(name, ()) if name else ():

@@ -10,6 +10,25 @@ POS = 3.0e38  # above any real score: parks retention-domain slots past a
 # shares one scratch width across buckets with different per-bucket K)
 
 
+def interpret_mode() -> bool:
+    """How every Pallas kernel of this package runs, decided at trace time.
+
+    ``cpu`` runs the kernel bodies in Pallas interpret mode, ``tpu``
+    compiles them with Mosaic. Any other backend is an error: no kernel
+    here has a lowering for it, and silently interpreting on an
+    accelerator would hide the device.
+    """
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (Mosaic) or 'cpu' (interpret mode); "
+        f"the default JAX backend is {backend!r}"
+    )
+
+
 def argmin_onehot(rd: jax.Array):
     """Per-row one-hot of the FIRST minimum of ``rd`` (rows, K) plus the min.
 

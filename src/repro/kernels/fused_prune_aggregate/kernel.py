@@ -7,7 +7,7 @@ K1  ``prune``: streams per-edge decomposed coefficients θ_u* (+ relation
     term) in neighbor tiles, maintains the K-slot retention domain (ranking
     scalar, per-head θ vector, slot id) in VMEM scratch, and at the last
     tile applies LeakyReLU(θ_u*+θ_*v), masks, and softmaxes over the
-    retained set — emitting attention weights α (T,K,H) and slot ids (T,K).
+    retained set — emitting attention weights α (H,T,K) and slot ids (T,K).
     Pruned neighbors never have their importance computed (paper §4.1) and
     their feature rows are never read.
 
@@ -20,7 +20,13 @@ K2  ``gather-aggregate``: scalar-prefetch (PrefetchScalarGridSpec) kernel;
 The full (T, D, H·dh) gathered-feature tensor of the staged flow is never
 materialized anywhere.
 
-Two grid shapes share the K1/K2 bodies:
+Layout, chosen so Mosaic compiles it: every tile keeps targets in sublanes
+and neighbor slots in lanes, and per-head arrays carry the head as a
+leading axis (θ tiles (H, Tt, W), retained θ (H, Tt, K), α (H, T, K)). A
+tile's column is read with a masked lane max, never a dynamic lane slice.
+K2's per-step scalars are three 1-D vectors (row, slot, source id).
+
+Two grid shapes share the K1 body and the K2 kernel:
 
   * **flat** (``fused_prune_aggregate_pallas``): one ``(T, D)`` padded-CSC
     table, rectangular grid ``(T/T_TILE, D/D_TILE)``.
@@ -46,15 +52,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG, POS, min_replace
+from repro.kernels import common
+from repro.kernels.common import NEG, POS, argmin_onehot
 
 T_TILE = 8
 D_TILE = 128
 # grouped ragged grid: D-tile width. Narrow so capacity-8/16/32 buckets pay
-# at most w-1 padded slots per row; the lane-dim payload of K1 is H anyway.
+# at most w-1 padded slots per row, at the price of K1 tiles that fill 8 of
+# a vreg's 128 lanes.
 W_TILE = 8
 
 # trace-time launch accounting: how many pallas_call sites were traced and
@@ -66,91 +75,189 @@ W_TILE = 8
 DISPATCH = {"pallas_calls": 0, "grouped_traces": 0, "sharded_traces": 0}
 
 
-def _prune_kernel(
-    theta_g_ref,  # (Tt, Dt, H) θ_u* (+rel) per edge slot
-    mask_ref,  # (Tt, Dt) int32
-    theta_dst_ref,  # (Tt, H)
-    gid_ref,  # (Tt, Dt) int32 global source ids
-    alpha_ref,  # out (Tt, K, H)
+def _pick(x: jax.Array, lane: jax.Array, j) -> jax.Array:
+    """Column ``j`` of ``x`` along its lane (last) axis, kept as a size-1
+    axis. A masked max, exact for every value: Mosaic has no dynamic lane
+    slice, and this serves a traced ``j`` and a static one alike."""
+    floating = np.issubdtype(x.dtype, np.floating)
+    fill = -np.inf if floating else np.iinfo(x.dtype).min
+    return jnp.max(jnp.where(lane == j, x, fill), axis=-1, keepdims=True)
+
+
+def _prune_body(
+    dt,  # D-tile index of this step within its row block
+    n_dt,  # D-tiles of the row block
+    k_eff,  # effective K of the row block; slots past it park at +inf
+    bypass,  # §4.3 direct-copy flag, or None where no step can set it
+    theta_ref,  # (H, Tt, W) θ_u* (+rel) per edge slot, head-major
+    mask_ref,  # (Tt, W) int32
+    gid_ref,  # (Tt, W) int32 global source ids
+    theta_dst_ref,  # (Tt, H) θ_*v of the row block
+    alpha_ref,  # out (H, Tt, K) softmax weights of the retained slots
     ids_ref,  # out (Tt, K) retained global ids (-1 = empty)
-    rd_rank,  # scratch (Tt, K) f32
-    rd_theta,  # scratch (Tt, K, H) f32
+    rd_rank,  # scratch (Tt, K) f32 ranking scalar per retained slot
+    rd_theta,  # scratch (H, Tt, K) f32 per-head θ_u* per retained slot
     rd_id,  # scratch (Tt, K) i32
     *,
     slope: float,
 ):
-    d_idx = pl.program_id(1)
+    """K1 on one (Tt, W) tile: Algorithm-1 retention-domain update, and at
+    the row block's last D-tile the masked LeakyReLU softmax over it.
 
-    @pl.when(d_idx == 0)
+    Every array keeps targets in sublanes and slots in lanes; per-head
+    arrays carry the head as a leading axis. The column walk is a
+    ``fori_loop`` whose candidate column is a masked lane max (``_pick``),
+    so no step needs a dynamic lane slice — the layout Mosaic compiles."""
+    h, t_tile, w = theta_ref.shape
+    k = rd_rank.shape[-1]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (t_tile, k), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t_tile, w), 1)
+
+    @pl.when(dt == 0)
     def _init():
-        rd_rank[...] = jnp.full_like(rd_rank, NEG)
+        rd_rank[...] = jnp.where(slot < k_eff, NEG, POS)
         rd_theta[...] = jnp.zeros_like(rd_theta)
         rd_id[...] = jnp.full_like(rd_id, -1)
 
-    theta = theta_g_ref[...]  # (Tt, Dt, H)
-    rank = jnp.where(mask_ref[...] != 0, theta.sum(-1), NEG)  # (Tt, Dt)
-    gids = gid_ref[...]
+    theta = theta_ref[...]  # (H, Tt, W)
+    valid = mask_ref[...] != 0
+    rank = jnp.where(valid, theta.sum(0), NEG)  # (Tt, W)
+    gids = jnp.where(valid, gid_ref[...], -1)
 
-    def step(j, _):
-        cur = jax.lax.dynamic_slice_in_dim(rank, j, 1, axis=1)[:, 0]
-        cur_th = jax.lax.dynamic_slice_in_dim(theta, j, 1, axis=1)[:, 0, :]
-        cur_id = jax.lax.dynamic_slice_in_dim(gids, j, 1, axis=1)[:, 0]
-        new_rank, (new_id, new_th) = min_replace(
-            rd_rank[...],
-            [(rd_id[...], cur_id), (rd_theta[...], cur_th)],
-            cur,
-            None,
+    def walk(update):
+        def step(j, carry):
+            rr, ri, rt = carry
+            cur = _pick(rank, col, j)  # (Tt, 1)
+            at = update(j, rr, cur)  # (Tt, K) slot receiving column j
+            return (
+                jnp.where(at, cur, rr),
+                jnp.where(at, _pick(gids, col, j), ri),
+                jnp.where(at[None], _pick(theta, col[None], j), rt),
+            )
+
+        rr, ri, rt = jax.lax.fori_loop(
+            0, w, step, (rd_rank[...], rd_id[...], rd_theta[...])
         )
-        rd_rank[...] = new_rank
-        rd_id[...] = new_id
-        rd_theta[...] = new_th
-        return 0
+        rd_rank[...] = rr
+        rd_id[...] = ri
+        rd_theta[...] = rt
 
-    jax.lax.fori_loop(0, D_TILE, step, 0)
+    def replace_min(j, rr, cur):
+        # strict '>' keeps the incumbent on ties; the FIRST minimum goes
+        onehot, m = argmin_onehot(rr)
+        return onehot & (cur > m)
 
-    @pl.when(d_idx == pl.num_programs(1) - 1)
+    # static guard: a bypass bucket's k_eff is its padded capacity (≥ w), so
+    # K < w proves no step sets the flag — and the w slots the copy fills
+    # would not fit the scratch (pl.when still traces untaken branches)
+    if bypass is not None and k >= w:
+
+        @pl.when(bypass != 0)
+        def _direct():
+            # §4.3 pruner bypass, in-kernel: capacity ≤ K means every
+            # candidate is retained, so its slot is known statically from
+            # the tile column — a straight copy, no argmin per column
+            walk(lambda j, rr, cur: slot == dt * w + j)
+
+        @pl.when(bypass == 0)
+        def _insert():
+            walk(replace_min)
+
+    else:
+        walk(replace_min)
+
+    @pl.when(dt == n_dt - 1)
     def _flush():
-        valid = rd_rank[...] > NEG / 2  # (Tt, K)
-        th = rd_theta[...] + theta_dst_ref[...][:, None, :]
+        ok = (rd_rank[...] > NEG / 2) & (slot < k_eff)  # (Tt, K)
+        # θ_*v per head as (H, Tt, 1): the diagonal of (H, Tt, H)
+        cube = (h, t_tile, h)
+        th_dst = _pick(
+            jnp.broadcast_to(theta_dst_ref[...][None], cube),
+            jax.lax.broadcasted_iota(jnp.int32, cube, 2),
+            jax.lax.broadcasted_iota(jnp.int32, cube, 0),
+        )
+        th = rd_theta[...] + th_dst  # (H, Tt, K)
         th = jnp.where(th >= 0, th, slope * th)  # LeakyReLU
-        th = jnp.where(valid[..., None], th, NEG)
-        mx = jnp.max(th, axis=1, keepdims=True)
-        ex = jnp.exp(th - mx)
-        ex = jnp.where(valid[..., None], ex, 0.0)
-        alpha_ref[...] = ex / (ex.sum(axis=1, keepdims=True) + 1e-30)
-        ids_ref[...] = jnp.where(valid, rd_id[...], -1)
+        th = jnp.where(ok[None], th, NEG)
+        mx = jnp.max(th, axis=-1, keepdims=True)
+        ex = jnp.where(ok[None], jnp.exp(th - mx), 0.0)
+        alpha_ref[...] = ex / (ex.sum(axis=-1, keepdims=True) + 1e-30)
+        ids_ref[...] = jnp.where(ok, rd_id[...], -1)
 
 
-def _aggregate_kernel(ids_ref, alpha_ref, h_ref, out_ref):
-    # grid (T, K): one retained feature row per step, accumulated in VMEM.
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    a = alpha_ref[0, k, :]  # (H,)
-    row = h_ref[...]  # (1, H, dh) — DMA'd via ids_ref index_map
-    out_ref[...] += a[None, :, None] * row
+def _prune_kernel(*refs, k_eff: int, slope: float):
+    # flat grid (T/Tt, D/Dt): every row block prunes to the same K
+    d_idx = pl.program_id(1)
+    _prune_body(d_idx, pl.num_programs(1), k_eff, None, *refs, slope=slope)
 
 
-def _grouped_aggregate_kernel(meta_ref, ids_ref, alpha_ref, h_ref, out_ref):
-    # ragged 1-D grid: step s accumulates retention slot meta[1, s] of
-    # output row meta[0, s]. Rows of narrow buckets contribute only their
-    # own effective-K steps, not the shared scratch width K_s.
-    s = pl.program_id(0)
-    slot = meta_ref[1, s]
+def _grouped_prune_kernel(meta_ref, *refs, slope: float):
+    # ragged 1-D grid: meta rows (row_block, dt, n_dt, bypass, k_eff)
+    g = pl.program_id(0)
+    _prune_body(
+        meta_ref[1, g], meta_ref[2, g], meta_ref[4, g], meta_ref[3, g],
+        *refs, slope=slope,
+    )
+
+
+def _aggregate_kernel(row_ref, slot_ref, src_ref, alpha_ref, h_ref, out_ref):
+    # 1-D grid: step s accumulates retention slot slot[s] of output row
+    # row[s]; the index maps DMA that row's α (H, K) and the one feature
+    # row h'[src[s]] (H, dh). Steps of a row are consecutive, from slot 0.
+    del row_ref, src_ref  # index maps only
+    slot = slot_ref[pl.program_id(0)]
 
     @pl.when(slot == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = alpha_ref[0, slot, :]  # (H,)
-    row = h_ref[...]  # (1, H, dh) — DMA'd via the ids/meta index_map
-    out_ref[...] += a[None, :, None] * row
+    a = alpha_ref[...]  # (H, K)
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    out_ref[...] += _pick(a, lane, slot) * h_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("prune_k", "slope", "interpret"))
+def _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj):
+    """K2: Σ_slot α·h'[id] per output row, one retained row DMA per step.
+
+    ``alpha`` is K1's (H, rows, K) output, ``ids`` its (rows, K) slot ids;
+    ``agg_row``/``agg_slot`` (S,) list the (row, slot) pairs to gather, each
+    row's slots consecutive and starting at 0. Returns (rows, H, dh) f32.
+    """
+    h, rows, k = alpha.shape
+    n, _, dh = h_proj.shape
+    src = jnp.maximum(ids[agg_row, agg_slot], 0)  # α is 0 on empty slots
+    DISPATCH["pallas_calls"] += 1
+    return pl.pallas_call(
+        _aggregate_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(agg_row.shape[0],),
+            in_specs=[
+                pl.BlockSpec(
+                    (pl.Squeezed(), h, k), lambda s, r, sl, src: (r[s], 0, 0)
+                ),
+                pl.BlockSpec(
+                    (pl.Squeezed(), h, dh), lambda s, r, sl, src: (src[s], 0, 0)
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (pl.Squeezed(), h, dh), lambda s, r, sl, src: (r[s], 0, 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, h, dh), jnp.float32),
+        interpret=common.interpret_mode(),
+    )(agg_row, agg_slot, src, alpha.transpose(1, 0, 2), h_proj.astype(jnp.float32))
+
+
+def _prune_scratch(t_tile: int, k: int, h: int):
+    return [
+        pltpu.VMEM((t_tile, k), jnp.float32),
+        pltpu.VMEM((h, t_tile, k), jnp.float32),
+        pltpu.VMEM((t_tile, k), jnp.int32),
+    ]
+
+
+@functools.partial(jax.jit, static_argnames=("prune_k", "slope"))
 def fused_prune_aggregate_pallas(
     theta_g: jax.Array,  # (T, D, H)
     mask: jax.Array,  # (T, D)
@@ -159,10 +266,8 @@ def fused_prune_aggregate_pallas(
     h_proj: jax.Array,  # (N, H, dh)
     prune_k: int,
     slope: float = 0.2,
-    interpret: bool = True,
 ) -> jax.Array:
     t, d, h = theta_g.shape
-    n, _, dh = h_proj.shape
     k = min(prune_k, d)
     tp, dp = (-t) % T_TILE, (-d) % D_TILE
     theta_g = jnp.pad(theta_g.astype(jnp.float32), ((0, tp), (0, dp), (0, 0)))
@@ -173,134 +278,33 @@ def fused_prune_aggregate_pallas(
 
     DISPATCH["pallas_calls"] += 1
     alpha, ids = pl.pallas_call(
-        functools.partial(_prune_kernel, slope=slope),
+        functools.partial(_prune_kernel, k_eff=k, slope=slope),
         grid=(tt // T_TILE, dd // D_TILE),
         in_specs=[
-            pl.BlockSpec((T_TILE, D_TILE, h), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((h, T_TILE, D_TILE), lambda i, j: (0, i, j)),
+            pl.BlockSpec((T_TILE, D_TILE), lambda i, j: (i, j)),
             pl.BlockSpec((T_TILE, D_TILE), lambda i, j: (i, j)),
             pl.BlockSpec((T_TILE, h), lambda i, j: (i, 0)),
-            pl.BlockSpec((T_TILE, D_TILE), lambda i, j: (i, j)),
         ],
         out_specs=[
-            pl.BlockSpec((T_TILE, k, h), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((h, T_TILE, k), lambda i, j: (0, i, 0)),
             pl.BlockSpec((T_TILE, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((tt, k, h), jnp.float32),
+            jax.ShapeDtypeStruct((h, tt, k), jnp.float32),
             jax.ShapeDtypeStruct((tt, k), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((T_TILE, k), jnp.float32),
-            pltpu.VMEM((T_TILE, k, h), jnp.float32),
-            pltpu.VMEM((T_TILE, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(theta_g, mask, theta_dst, gid)
+        scratch_shapes=_prune_scratch(T_TILE, k, h),
+        interpret=common.interpret_mode(),
+    )(theta_g.transpose(2, 0, 1), mask, gid, theta_dst)
 
-    ids_safe = jnp.maximum(ids, 0)  # α is 0 on empty slots
-    DISPATCH["pallas_calls"] += 1
-    out = pl.pallas_call(
-        _aggregate_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(tt, k),
-            in_specs=[
-                pl.BlockSpec((1, k, h), lambda i, j, ids: (i, 0, 0)),
-                pl.BlockSpec((1, h, dh), lambda i, j, ids: (ids[i, j], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, h, dh), lambda i, j, ids: (i, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((tt, h, dh), jnp.float32),
-        interpret=interpret,
-    )(ids_safe, alpha, h_proj.astype(jnp.float32))
+    agg_row = jnp.repeat(jnp.arange(tt, dtype=jnp.int32), k)
+    agg_slot = jnp.tile(jnp.arange(k, dtype=jnp.int32), tt)
+    out = _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj)
     return out[:t]
 
 
-def _grouped_prune_kernel(
-    meta_ref,  # (5, G) SMEM: row_block, dt, n_dt, bypass, k_eff per step
-    theta_g_ref,  # (1, Tt, W, H) θ_u* (+rel) tile, grid-ordered
-    mask_ref,  # (1, Tt, W) int32
-    gid_ref,  # (1, Tt, W) int32 global source ids
-    theta_dst_ref,  # (1, Tt, H) — θ_*v rows of this step's row block
-    alpha_ref,  # out (1, Tt, K_s, H)
-    ids_ref,  # out (1, Tt, K_s) retained global ids (-1 = empty)
-    rd_rank,  # scratch (Tt, K_s) f32
-    rd_theta,  # scratch (Tt, K_s, H) f32
-    rd_id,  # scratch (Tt, K_s) i32
-    *,
-    slope: float,
-    w: int,
-):
-    g = pl.program_id(0)
-    dt = meta_ref[1, g]
-    n_dt = meta_ref[2, g]
-    bypass = meta_ref[3, g]
-    k_eff = meta_ref[4, g]
-    slot = jax.lax.broadcasted_iota(jnp.int32, rd_rank.shape, 1)
-
-    @pl.when(dt == 0)
-    def _init():
-        # slots past this bucket's effective K park at +inf: never the
-        # argmin, never replaced — one scratch width serves every bucket
-        rd_rank[...] = jnp.where(slot < k_eff, NEG, POS)
-        rd_theta[...] = jnp.zeros_like(rd_theta)
-        rd_id[...] = jnp.full_like(rd_id, -1)
-
-    theta = theta_g_ref[0]  # (Tt, W, H)
-    valid = mask_ref[0] != 0
-    rank = jnp.where(valid, theta.sum(-1), NEG)  # (Tt, W)
-    gids = jnp.where(valid, gid_ref[0], -1)
-
-    # static guard: a bypass bucket's k_eff is its padded capacity (≥ w), so
-    # K_s < w proves no step sets the flag — and the w-wide slice below
-    # would not fit the scratch (pl.when still traces untaken branches)
-    if rd_rank.shape[-1] >= w:
-
-        @pl.when(bypass != 0)
-        def _direct():
-            # §4.3 pruner bypass, in-kernel: capacity ≤ K means every
-            # candidate is retained, so its slot is known statically from
-            # the tile column — a straight copy, no O(W) min-replace scan
-            col = dt * w
-            rd_rank[:, pl.ds(col, w)] = rank
-            rd_id[:, pl.ds(col, w)] = gids
-            rd_theta[:, pl.ds(col, w), :] = theta
-
-    @pl.when(bypass == 0)
-    def _insert():
-        def step(j, _):
-            cur = jax.lax.dynamic_slice_in_dim(rank, j, 1, axis=1)[:, 0]
-            cur_th = jax.lax.dynamic_slice_in_dim(theta, j, 1, axis=1)[:, 0, :]
-            cur_id = jax.lax.dynamic_slice_in_dim(gids, j, 1, axis=1)[:, 0]
-            new_rank, (new_id, new_th) = min_replace(
-                rd_rank[...],
-                [(rd_id[...], cur_id), (rd_theta[...], cur_th)],
-                cur,
-                None,
-            )
-            rd_rank[...] = new_rank
-            rd_id[...] = new_id
-            rd_theta[...] = new_th
-            return 0
-
-        jax.lax.fori_loop(0, w, step, 0)
-
-    @pl.when(dt == n_dt - 1)
-    def _flush():
-        ok = (rd_rank[...] > NEG / 2) & (slot < k_eff)  # (Tt, K_s)
-        th = rd_theta[...] + theta_dst_ref[0][:, None, :]
-        th = jnp.where(th >= 0, th, slope * th)  # LeakyReLU
-        th = jnp.where(ok[..., None], th, NEG)
-        mx = jnp.max(th, axis=1, keepdims=True)
-        ex = jnp.exp(th - mx)
-        ex = jnp.where(ok[..., None], ex, 0.0)
-        alpha_ref[0] = ex / (ex.sum(axis=1, keepdims=True) + 1e-30)
-        ids_ref[0] = jnp.where(ok, rd_id[...], -1)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k_s", "t_tile", "w", "slope", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("k_s", "t_tile", "w", "slope"))
 def fused_prune_aggregate_grouped_pallas(
     theta_g: jax.Array,  # (G, t_tile, w, H) grid-ordered θ_u* (+rel) tiles
     mask: jax.Array,  # (G, t_tile, w)
@@ -314,7 +318,6 @@ def fused_prune_aggregate_grouped_pallas(
     t_tile: int = T_TILE,
     w: int = W_TILE,
     slope: float = 0.2,
-    interpret: bool = True,
 ) -> jax.Array:
     """Single-launch NA over all buckets of a grouped layout.
 
@@ -330,57 +333,39 @@ def fused_prune_aggregate_grouped_pallas(
     """
     grid_steps, _, _, h = theta_g.shape
     r = theta_dst_rows.shape[0]
-    n, _, dh = h_proj.shape
     rows = r * t_tile
+    sq = pl.Squeezed()
 
     DISPATCH["pallas_calls"] += 1
     alpha, ids = pl.pallas_call(
-        functools.partial(_grouped_prune_kernel, slope=slope, w=w),
+        functools.partial(_grouped_prune_kernel, slope=slope),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(grid_steps,),
             in_specs=[
-                pl.BlockSpec((1, t_tile, w, h), lambda g, m: (g, 0, 0, 0)),
-                pl.BlockSpec((1, t_tile, w), lambda g, m: (g, 0, 0)),
-                pl.BlockSpec((1, t_tile, w), lambda g, m: (g, 0, 0)),
-                pl.BlockSpec((1, t_tile, h), lambda g, m: (m[0, g], 0, 0)),
+                pl.BlockSpec((sq, h, t_tile, w), lambda g, m: (g, 0, 0, 0)),
+                pl.BlockSpec((sq, t_tile, w), lambda g, m: (g, 0, 0)),
+                pl.BlockSpec((sq, t_tile, w), lambda g, m: (g, 0, 0)),
+                pl.BlockSpec((t_tile, h), lambda g, m: (m[0, g], 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, t_tile, k_s, h), lambda g, m: (m[0, g], 0, 0, 0)),
-                pl.BlockSpec((1, t_tile, k_s), lambda g, m: (m[0, g], 0, 0)),
+                pl.BlockSpec((h, t_tile, k_s), lambda g, m: (0, m[0, g], 0)),
+                pl.BlockSpec((t_tile, k_s), lambda g, m: (m[0, g], 0)),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((t_tile, k_s), jnp.float32),
-                pltpu.VMEM((t_tile, k_s, h), jnp.float32),
-                pltpu.VMEM((t_tile, k_s), jnp.int32),
-            ],
+            scratch_shapes=_prune_scratch(t_tile, k_s, h),
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((r, t_tile, k_s, h), jnp.float32),
-            jax.ShapeDtypeStruct((r, t_tile, k_s), jnp.int32),
+            jax.ShapeDtypeStruct((h, rows, k_s), jnp.float32),
+            jax.ShapeDtypeStruct((rows, k_s), jnp.int32),
         ],
-        interpret=interpret,
-    )(meta, theta_g.astype(jnp.float32), mask.astype(jnp.int32),
-      gid.astype(jnp.int32), theta_dst_rows.astype(jnp.float32))
+        interpret=common.interpret_mode(),
+    )(
+        meta,
+        theta_g.astype(jnp.float32).transpose(0, 3, 1, 2),
+        mask.astype(jnp.int32),
+        gid.astype(jnp.int32),
+        theta_dst_rows.astype(jnp.float32).reshape(rows, h),
+    )
 
-    alpha = alpha.reshape(rows, k_s, h)
-    ids = ids.reshape(rows, k_s)
-    ids_safe = jnp.maximum(ids, 0)  # α is 0 on empty slots
-    DISPATCH["pallas_calls"] += 1
-    out = pl.pallas_call(
-        _grouped_aggregate_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(agg_meta.shape[1],),
-            in_specs=[
-                pl.BlockSpec((1, k_s, h), lambda s, m, ids: (m[0, s], 0, 0)),
-                pl.BlockSpec(
-                    (1, h, dh), lambda s, m, ids: (ids[m[0, s], m[1, s]], 0, 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec((1, h, dh), lambda s, m, ids: (m[0, s], 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, h, dh), jnp.float32),
-        interpret=interpret,
-    )(agg_meta, ids_safe, alpha, h_proj.astype(jnp.float32))
+    out = _gather_aggregate(alpha, ids, agg_meta[0], agg_meta[1], h_proj)
     return out if perm is None else out[perm]

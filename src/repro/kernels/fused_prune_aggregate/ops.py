@@ -42,7 +42,6 @@ def fused_prune_aggregate(
     edge_type: Optional[jax.Array] = None,  # (T, D)
     prune_k: Optional[int] = None,
     slope: float = 0.2,
-    interpret: bool = True,
 ) -> jax.Array:
     # The scalar pass: θ_u* per edge slot. 4·H bytes/edge instead of the
     # 4·H·dh bytes/edge feature row the staged flow gathers.
@@ -52,7 +51,7 @@ def fused_prune_aggregate(
     k = prune_k if prune_k is not None else nbr_idx.shape[1]
     return fused_prune_aggregate_pallas(
         theta_g, nbr_mask, theta_dst, nbr_idx, h_proj,
-        prune_k=k, slope=slope, interpret=interpret,
+        prune_k=k, slope=slope,
     )
 
 
@@ -130,12 +129,12 @@ def _layout_device(layout, prune_k: Optional[int]):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k_s", "t_tile", "w", "slope", "interpret", "use_rel"),
+    static_argnames=("k_s", "t_tile", "w", "slope", "use_rel"),
 )
 def _grouped_call(
     h_proj, theta_src, theta_dst, theta_rel,
     nbr, msk, ety, row_targets, meta, agg_meta, perm,
-    k_s, t_tile, w, slope, interpret, use_rel,
+    k_s, t_tile, w, slope, use_rel,
 ):
     DISPATCH["grouped_traces"] += 1
     theta_g = theta_src[nbr]  # (G, t_tile, w, H)
@@ -145,7 +144,7 @@ def _grouped_call(
     td_rows = theta_dst[row_targets].reshape(-1, t_tile, h)
     return fused_prune_aggregate_grouped_pallas(
         theta_g, msk, nbr, td_rows, meta, agg_meta, h_proj, perm,
-        k_s=k_s, t_tile=t_tile, w=w, slope=slope, interpret=interpret,
+        k_s=k_s, t_tile=t_tile, w=w, slope=slope,
     )
 
 
@@ -157,7 +156,6 @@ def fused_prune_aggregate_grouped(
     theta_rel: Optional[jax.Array] = None,  # (R, H)
     prune_k: Optional[int] = None,
     slope: float = 0.2,
-    interpret: bool = True,
     t_tile: int = T_TILE,
     w: int = W_TILE,
 ) -> jax.Array:
@@ -177,8 +175,7 @@ def fused_prune_aggregate_grouped(
         h_proj, theta_src, theta_dst,
         theta_rel if use_rel else None,
         nbr, msk, ety, row_targets, meta, agg_meta, perm,
-        k_s=k_s, t_tile=t_tile, w=w, slope=slope, interpret=interpret,
-        use_rel=use_rel,
+        k_s=k_s, t_tile=t_tile, w=w, slope=slope, use_rel=use_rel,
     )
 
 
@@ -263,7 +260,6 @@ def fused_prune_aggregate_grouped_sharded(
     theta_rel: Optional[jax.Array] = None,  # (R, H)
     prune_k: Optional[int] = None,
     slope: float = 0.2,
-    interpret: bool = True,
     t_tile: int = T_TILE,
     w: int = W_TILE,
 ) -> jax.Array:
@@ -286,7 +282,7 @@ def fused_prune_aggregate_grouped_sharded(
         sl, prune_k
     )
     use_rel = theta_rel is not None
-    fn = _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope, interpret)
+    fn = _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope)
     args = (nbr, msk, ety, row_targets, meta, agg_meta, h_proj, theta_src,
             theta_dst) + ((theta_rel,) if use_rel else ())
     out = fn(*args)
@@ -297,7 +293,7 @@ def fused_prune_aggregate_grouped_sharded(
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope, interpret):
+def _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope):
     """The jitted shard_map body for one (mesh, static-config) pair.
 
     Cached on those statics so repeated layers/steps reuse one callable —
@@ -317,7 +313,6 @@ def _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope, interpret):
         out = fused_prune_aggregate_grouped_pallas(
             theta_g, msk_s[0], nbr_s[0], td_rows, meta_s[0], agg_s[0], h_r,
             None, k_s=k_s, t_tile=t_tile, w=w, slope=slope,
-            interpret=interpret,
         )
         return out[None]  # (1, num_rows_alloc, H, dh)
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common
 from repro.kernels.common import NEG, min_replace
 
 S_TILE = 128
@@ -92,7 +93,7 @@ def _value_gather_kernel(ids_ref, alpha_ref, v_ref, out_ref, *, group: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("prune_k", "scale", "interpret")
+    jax.jit, static_argnames=("prune_k", "scale")
 )
 def topk_decode_attention_pallas(
     q: jax.Array,  # (B, H, dh)
@@ -101,7 +102,6 @@ def topk_decode_attention_pallas(
     lengths: jax.Array,  # (B,) valid prefix lengths
     prune_k: int,
     scale: float | None = None,
-    interpret: bool = True,
 ) -> jax.Array:
     b, h, dh = q.shape
     _, s, hkv, _ = k_cache.shape
@@ -134,7 +134,7 @@ def topk_decode_attention_pallas(
             pltpu.VMEM((h, kk), jnp.float32),
             pltpu.VMEM((h, kk), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(q.astype(jnp.float32), k_cache, lens)
 
     ids_safe = jnp.maximum(ids, 0)
@@ -154,6 +154,6 @@ def topk_decode_attention_pallas(
             out_specs=pl.BlockSpec((1, 1, dh), lambda i, j, l, ids: (i, j, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(ids_safe, alpha, jnp.pad(v_cache.astype(jnp.float32), ((0, 0), (0, sp), (0, 0), (0, 0))))
     return out

@@ -1,7 +1,7 @@
 """Public wrapper for ADE pruned decode attention.
 
 ``impl``:
-  * ``pallas`` — the kernel (TPU target; interpret-mode on CPU)
+  * ``pallas`` — the kernel (Mosaic on TPU, interpret mode on CPU)
   * ``xla``    — lax.top_k formulation; partitions under SPMD, used by the
                  sharded serve_step and the dry-run.
 """
@@ -17,12 +17,12 @@ from repro.kernels.topk_decode_attention.ref import (
 
 def topk_decode_attention(
     q, k_cache, v_cache, lengths, prune_k=None, scale=None,
-    impl: str = "xla", interpret: bool = True,
+    impl: str = "xla",
 ):
     if prune_k is None:
         return full_decode_attention_ref(q, k_cache, v_cache, lengths, scale)
     if impl == "pallas":
         return topk_decode_attention_pallas(
-            q, k_cache, v_cache, lengths, prune_k, scale, interpret=interpret
+            q, k_cache, v_cache, lengths, prune_k, scale
         )
     return topk_decode_attention_ref(q, k_cache, v_cache, lengths, prune_k, scale)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common
 from repro.kernels.common import NEG, min_replace
 
 T_TILE = 8
@@ -53,12 +54,11 @@ def _pruner_kernel(scores_ref, mask_ref, out_s_ref, out_i_ref, rd_s, rd_i):
         out_i_ref[...] = jnp.where(rd_s[...] <= NEG / 2, -1, rd_i[...])
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k",))
 def topk_select_pallas(
     scores: jax.Array,  # (T, D) f32
     mask: jax.Array,  # (T, D) bool/int
     k: int,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     t, d = scores.shape
     tp = (-t) % T_TILE
@@ -86,6 +86,6 @@ def topk_select_pallas(
             pltpu.VMEM((T_TILE, k), jnp.float32),
             pltpu.VMEM((T_TILE, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(s, m)
     return out_s[:t], out_i[:t]

@@ -123,7 +123,7 @@ def _probe_costs(cfg, shape, mesh, kind: str, rules=None):
     """Compile the probe and return (flops, bytes, coll_bytes) per device."""
     from repro.distributed.probe import probe_mode
 
-    with shlib.set_mesh(mesh), shlib.axis_rules(rules or {}), probe_mode():
+    with jax.set_mesh(mesh), shlib.axis_rules(rules or {}), probe_mode():
         if kind == "train":
             params_s, opt_s = steps_lib.state_specs(cfg, with_opt=True)
             p_sh, o_sh = steps_lib.params_shardings(cfg, mesh, params_s, opt_s)
@@ -258,7 +258,7 @@ def run_cell(
 
     t0 = time.time()
     try:
-        with shlib.set_mesh(mesh), shlib.axis_rules(rules):
+        with jax.set_mesh(mesh), shlib.axis_rules(rules):
             if shape.kind == "train":
                 params_s, opt_s = steps_lib.state_specs(cfg, with_opt=True)
                 p_sh, o_sh = steps_lib.params_shardings(cfg, mesh, params_s, opt_s)

@@ -1,6 +1,7 @@
 """Small-mesh dry-run integration tests (subprocess: device count is locked
 at first jax init, so mesh tests get their own interpreter)."""
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -17,8 +18,12 @@ def _run(args, devices=8):
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "PYTHONPATH": str(ROOT / "src"),
         "PATH": "/usr/bin:/bin",
-        "HOME": "/tmp",
     }
+    # the child runs on the parent's platform (the suite runs on the CPU)
+    # and keeps the parent's home and scratch directories
+    for name in ("JAX_PLATFORMS", "HOME", "TMPDIR"):
+        if name in os.environ:
+            env[name] = os.environ[name]
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun"] + args,
         capture_output=True, text=True, env=env, timeout=900, cwd=str(ROOT),

@@ -92,6 +92,21 @@ def test_topk_select_pallas_all_masked_rows(rng):
         assert (ids[row] >= 0).sum() == want, row
 
 
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """One backend decision for every kernel: interpret on CPU, Mosaic on
+    TPU, and a loud error anywhere else."""
+    import jax
+
+    from repro.kernels import common
+
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert common.interpret_mode() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        common.interpret_mode()
+
+
 @pytest.mark.parametrize(
     "t,d,h,dh,n,k",
     [(11, 70, 8, 8, 200, 5), (8, 128, 8, 8, 64, 50), (5, 33, 4, 16, 40, 33),

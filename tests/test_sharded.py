@@ -32,7 +32,7 @@ KERNEL = FlowConfig("fused_kernel", prune_k=8)
 
 
 def _mesh(n):
-    return jax.sharding.Mesh(np.array(jax.devices()[:n]), ("data",))
+    return jax.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[:n]), ("data",)))
 
 
 def _reset():
