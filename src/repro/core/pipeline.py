@@ -95,9 +95,10 @@ class HGNNTask:
         donate_params: bool = False,
     ) -> InferenceSession:
         """The cached AOT serving entry: ONE executable per (flow, mesh,
-        dtype, donation) — repeated calls (``accuracy`` over splits, a
-        serving loop) share it. ``params`` only provides example avals for
-        lowering (defaults to the task's init params)."""
+        dtype, donation, default matmul precision) — repeated calls
+        (``accuracy`` over splits, a serving loop) share it. ``params`` only
+        provides example avals for lowering (defaults to the task's init
+        params)."""
         if params is None:
             params = self.params
         gm = dist_sharding.graph_mesh()
@@ -106,7 +107,10 @@ class HGNNTask:
         # different tree must get its own executable, not a stale one
         leaves, treedef = jax.tree_util.tree_flatten(params)
         avals = tuple((l.shape, str(l.dtype)) for l in leaves)
-        key = (flow, mesh_fingerprint(gm), treedef, avals, donate_params)
+        # the program is traced under the ambient
+        # jax.default_matmul_precision, so that is part of its identity
+        key = (flow, mesh_fingerprint(gm), treedef, avals, donate_params,
+               jax.config.jax_default_matmul_precision)
         sess = self._sessions.get(key)
         if sess is None:
             sess = InferenceSession(

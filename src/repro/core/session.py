@@ -62,6 +62,7 @@ import numpy as np
 from repro.core import flows
 from repro.core.batch import GraphBatch
 from repro.core.flows import FlowConfig
+from repro.core.hetgraph import BucketedSemanticGraph
 from repro.distributed import sharding as dist
 
 _UNSET = object()
@@ -74,6 +75,30 @@ def mesh_fingerprint(gm) -> Optional[Tuple]:
         return None
     mesh, axis, n = gm
     return (mesh, axis, n)
+
+
+def refuse_known_tpu_hang(batch: GraphBatch, flow: FlowConfig) -> None:
+    """Refuse a session whose program is known to hang on a TPU.
+
+    On a TPU v5e (jax 0.9.0) the single-program bucketed ``fused`` NA
+    (``flows._bucketed_aggregate``) of HAN on synthetic DBLP at
+    ``scale=1.0`` compiled but never finished, while each bucket's own
+    program ran. The cause is not known, so every such session is refused
+    on a TPU: a serving fallback that stalls on a breaker trip is worse
+    than one that is never built. ``bucket_dispatch="loop"`` and the other
+    flows are unaffected.
+    """
+    if (
+        flow.flow == "fused"
+        and flow.bucket_dispatch == "single"
+        and jax.default_backend() == "tpu"
+        and any(isinstance(sg, BucketedSemanticGraph) for sg in batch.sgs)
+    ):
+        raise NotImplementedError(
+            "the single-program bucketed 'fused' flow is known to hang on "
+            "TPU (TPU v5e, DBLP scale=1.0); use 'fused_kernel', "
+            "'staged_pruned' or FlowConfig(..., bucket_dispatch='loop')"
+        )
 
 
 class InferenceSession:
@@ -97,6 +122,7 @@ class InferenceSession:
             raise ValueError(
                 "InferenceSession needs example params to AOT-lower against"
             )
+        refuse_known_tpu_hang(batch, flow)
         if mesh_info is _UNSET:
             # the session's single mesh resolution — every traced NA
             # dispatch below reuses it via the pinned scope

@@ -183,19 +183,24 @@ def _sharded_device(sl, prune_k: Optional[int]):
     """Stacked jnp mirrors of a ``ShardedBucketLayout``, cached on it.
 
     SPMD needs every shard to run the same program on same-shaped operands,
-    so per-shard stacks are equalized: grid steps pad to the max shard's
-    count with filler steps aimed at the reserved pad block (all-masked
-    tiles — the strict-``>`` retention insert admits none of them and the
-    pad block's flush writes zero α), K2 gather steps pad with
-    ``(pad_row, slot 0)`` entries that accumulate that zero α into a row no
-    target's ``perm`` entry reads, and the retention-scratch width ``k_s``
+    so per-shard stacks are equalized: grid steps pad to one more than the
+    max shard's count with filler steps aimed at the reserved pad block
+    (all-masked tiles — the strict-``>`` retention insert admits none of
+    them and the pad block's flush writes zero α and −1 ids), K2 gather
+    steps pad with ``(pad_row, slot 0)`` entries that accumulate that zero
+    α into a row no target's ``perm`` entry reads, and the retention-scratch
+    width ``k_s``
     is the max across shards (narrower shards park the extra slots at +inf
     exactly like narrow buckets do, so per-target arithmetic — and its bit
     pattern — matches the single-device launch).
     """
     cache = sl._dev
     t_tile, w, n_sh = sl.t_tile, sl.w, sl.n_shards
-    g_max = max(sl.num_steps_max, 1)
+    # every shard gets at least one filler step, so K1 writes the pad
+    # block in every shard: K2's padding steps read its ids, and K1 outputs
+    # no step writes are uninitialised on a TPU (a DMA from such an id ran
+    # out of bounds and halted the chip)
+    g_max = sl.num_steps_max + 1
     pad_block = sl.pad_block
     pad_row = sl.num_rows_alloc - 1
     with jax.ensure_compile_time_eval():
@@ -232,18 +237,17 @@ def _sharded_device(sl, prune_k: Optional[int]):
             for s, (m, a) in enumerate(zip(metas, aggs)):
                 g, n_pad = m.shape[1], g_max - m.shape[1]
                 meta[s, :, :g] = m
-                if n_pad:
-                    # filler K1 steps: one pad block of n_pad D-tiles,
-                    # bypass off, k_eff 1 — flushes zero α at its last step
-                    meta[s, :, g:] = np.stack(
-                        [
-                            np.full(n_pad, pad_block),
-                            np.arange(n_pad),
-                            np.full(n_pad, n_pad),
-                            np.zeros(n_pad, np.int64),
-                            np.ones(n_pad, np.int64),
-                        ]
-                    ).astype(np.int32)
+                # filler K1 steps: one pad block of n_pad D-tiles, bypass
+                # off, k_eff 1 — flushes zero α at its last step
+                meta[s, :, g:] = np.stack(
+                    [
+                        np.full(n_pad, pad_block),
+                        np.arange(n_pad),
+                        np.full(n_pad, n_pad),
+                        np.zeros(n_pad, np.int64),
+                        np.ones(n_pad, np.int64),
+                    ]
+                ).astype(np.int32)
                 agg[s, :, : a.shape[1]] = a
                 agg[s, 0, a.shape[1]:] = pad_row  # slot stays 0
             cache[prune_k] = (jnp.asarray(meta), jnp.asarray(agg), k_s)

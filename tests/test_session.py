@@ -197,12 +197,46 @@ def test_session_zero_python_dispatch(tasks):
     assert fpa_kernel.DISPATCH["grouped_traces"] == 0
 
 
+def test_session_refuses_bucketed_fused_on_tpu(tasks, monkeypatch):
+    """On a TPU the single-program bucketed ``fused`` flow (known to hang
+    there) is refused before lowering; the per-bucket loop and the other
+    flows are not, and nothing is refused off the TPU."""
+    from repro.core import session as session_mod
+
+    task = tasks[("han", "acm")]
+    fused = FlowConfig("fused", prune_k=8)
+    session_mod.refuse_known_tpu_hang(task.batch, fused)  # cpu: allowed
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="known to hang"):
+        session_mod.InferenceSession(
+            task.model, task.batch, fused, params=task.params
+        )
+    for ok in (
+        FlowConfig("fused", prune_k=8, bucket_dispatch="loop"),
+        FlowConfig("staged_pruned", prune_k=8),
+        FlowConfig("fused_kernel", prune_k=8),
+    ):
+        session_mod.refuse_known_tpu_hang(task.batch, ok)
+
+
 def test_session_cache_keyed_on_flow(tasks):
     task = tasks[("han", "acm")]
     a = task.compile(FlowConfig("fused", prune_k=8))
     b = task.compile(FlowConfig("fused", prune_k=8))
     c = task.compile(FlowConfig("fused", prune_k=4))
     assert a is b and a is not c
+
+
+def test_session_cache_keyed_on_matmul_precision(tasks):
+    """A session compiled under jax.default_matmul_precision is its own
+    program, not the cached default-precision one."""
+    task = tasks[("han", "acm")]
+    flow = FlowConfig("fused", prune_k=8)
+    a = task.compile(flow)
+    with jax.default_matmul_precision("highest"):
+        b = task.compile(flow)
+        assert task.compile(flow) is b
+    assert a is not b and task.compile(flow) is a
 
 
 def test_session_batch_call(tasks):

@@ -530,3 +530,27 @@ def test_shard_layout_degenerate():
         assert sl.shards[s].num_rows > 0
     # cached: same object back
     assert sg.sharded(8) is sl
+
+
+@pytest.mark.parametrize("n_shards", [2, 4, 8])
+@pytest.mark.parametrize("prune_k", [4, 8, None])
+def test_sharded_k2_reads_only_rows_k1_writes(n_shards, prune_k):
+    """Every K2 gather step of every shard reads the ids and α of a row
+    block that one of the shard's K1 grid steps writes. K1 outputs no step
+    writes are uninitialised on a TPU (interpret mode cannot show it), and
+    a K2 step that DMAs the feature row of such an id halts the chip."""
+    from repro.kernels.fused_prune_aggregate import ops
+
+    for sg in _sharded_graphs():
+        sl = sg.sharded(n_shards)
+        if sl.num_steps_max == 0:
+            continue
+        _, (meta, agg, _) = ops._sharded_device(sl, prune_k)
+        meta, agg = np.asarray(meta), np.asarray(agg)
+        for s in range(n_shards):
+            written = np.unique(meta[s, 0])
+            read = np.unique(agg[s, 0] // sl.t_tile)
+            assert np.isin(read, written).all(), (
+                f"{sg.name} shard {s}: K2 reads row blocks "
+                f"{np.setdiff1d(read, written)} that no K1 step writes"
+            )
