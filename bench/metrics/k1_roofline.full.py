@@ -1,0 +1,13 @@
+"""K1 (fused_prune_aggregate's prune + softmax kernel) against its
+roofline: the least time its real-edge operations and bytes need
+(bench/costs.py) over its device time in the trace."""
+from bench.metrics_common import kernel_roofline
+
+# On the chip a device op is named by its HLO instruction. Both kernels of a
+# launch carry the name of their jitted wrapper; K1 returns the tuple (α,
+# ids), K2 one float32 array of rows.
+PATTERN = r"^%fused_prune_aggregate_grouped_pallas[.\d]* = \("
+
+
+def read(ctx):
+    return kernel_roofline(ctx, PATTERN, "k1_ops", "k1_bytes")
