@@ -141,7 +141,7 @@ def bench_model(model: str, scale: float, assert_speedup: bool):
     assert dispatch["mesh_lookups"] == 0, dispatch
     assert dispatch["traces"] == 0, dispatch
 
-    mean_batch = float(np.mean(stats.block_sizes))
+    mean_batch = stats.valid_slots / stats.blocks
     speedup = t_serial / t_micro
     emit(
         f"serve_{model}_serial", t_serial / len(wl) * 1e6,

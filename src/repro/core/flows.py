@@ -276,7 +276,14 @@ def run_aggregate_graph(
     ``scores.theta_dst`` must cover the graph's full target range (one row
     per ``dst_type`` vertex, in local order). Bucketed graphs run as one
     dispatch (see module docstring) unless ``cfg.bucket_dispatch="loop"``.
+    Its ops carry the compile-time scope ``na.<graph name>``; the kernel
+    pair inside it adds ``k1`` and ``k2``.
     """
+    with jax.named_scope(f"na.{sg.name}"):
+        return _aggregate_graph(cfg, h_proj, scores, sg)
+
+
+def _aggregate_graph(cfg, h_proj, scores, sg) -> jax.Array:
     use_ety = scores.theta_rel is not None
     if isinstance(sg, BucketedSemanticGraph):
         # repro: allow(dispatch-in-traced) -- trace-time tick is the point

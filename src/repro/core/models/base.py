@@ -103,14 +103,20 @@ class HGNNModel:
         Wrapped in one ``flows.mesh_scope()`` so the ambient mesh is
         resolved AT MOST ONCE per apply (and not at all for flows that
         never consult it), however many NA dispatches the model issues.
+        The stages carry the compile-time scopes ``fp`` (projection) and
+        ``fusion`` (fuse and readout); each NA dispatch carries
+        ``na.<semantic graph>`` (``flows.run_aggregate_graph``).
         """
         with flows.mesh_scope():
             carry: Carry = dict(batch.features)
             for step in self.layer_steps(params, batch, flow):
-                h = step.project(carry)
+                with jax.named_scope("fp"):
+                    h = step.project(carry)
                 zs = {name: fn(h) for name, fn in step.na}
-                carry = step.fuse(carry, h, zs)
-            return self.readout(params, batch, carry)
+                with jax.named_scope("fusion"):
+                    carry = step.fuse(carry, h, zs)
+            with jax.named_scope("fusion"):
+                return self.readout(params, batch, carry)
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import flows
 from repro.core.batch import GraphBatch
 from repro.core.flows import FlowConfig
@@ -99,6 +100,12 @@ def refuse_known_tpu_hang(batch: GraphBatch, flow: FlowConfig) -> None:
             "TPU (TPU v5e, DBLP scale=1.0); use 'fused_kernel', "
             "'staged_pruned' or FlowConfig(..., bucket_dispatch='loop')"
         )
+
+
+def _gather(out, idx):
+    """The query rows of a forward's output, under the scope ``gather``."""
+    with jax.named_scope("gather"):
+        return out[idx]
 
 
 class InferenceSession:
@@ -142,6 +149,7 @@ class InferenceSession:
         )
         self.lowered = self._jitted.lower(params)
         self._executable = self.lowered.compile()
+        tracing.record_scopes(self._executable)
         # query-sliced serving state: the output aval (shape/dtype AND
         # sharding, so gather programs accept the executable's committed
         # output under a mesh) plus one cached gather program per block
@@ -157,7 +165,8 @@ class InferenceSession:
 
     def __call__(self, params) -> jax.Array:
         """(num_targets, num_classes) logits; one executable dispatch."""
-        return self._executable(params)
+        with tracing.span("session.forward"):
+            return self._executable(params)
 
     # -- query-sliced serving ---------------------------------------------
     def _output_aval(self, fn, params):
@@ -181,10 +190,11 @@ class InferenceSession:
             raise ValueError(f"query capacity must be >= 1, got {capacity}")
         exe = self._gathers.get(capacity)
         if exe is None:
-            exe = jax.jit(lambda out, idx: out[idx]).lower(
+            exe = jax.jit(_gather).lower(
                 self._out_aval,
                 jax.ShapeDtypeStruct((capacity,), jnp.int32),
             ).compile()
+            tracing.record_scopes(exe)
             self._gathers[capacity] = exe
         return exe
 
@@ -202,9 +212,10 @@ class InferenceSession:
             raise ValueError(f"query block must be a 1-D id vector, got "
                              f"shape {idx.shape}")
         gather = self.compile_query(idx.shape[0])
-        out = self._executable(params)
-        flows.DISPATCH["query_calls"] += 1
-        return gather(out, idx)
+        with tracing.span("session.query", capacity=idx.shape[0]):
+            out = self._executable(params)
+            flows.DISPATCH["query_calls"] += 1
+            return gather(out, idx)
 
     def prewarm(self, capacities: Sequence[int]) -> "InferenceSession":
         """Pre-compile the gather ladder for every capacity in one shot.
@@ -270,9 +281,10 @@ class InferenceSession:
 
             def fn(p, b):
                 with flows.mesh_scope(pinned=None):
-                    return model.apply(p, b, flow)[b.out_rows]
+                    return _gather(model.apply(p, b, flow), b.out_rows)
 
             exe = jax.jit(fn).lower(params, ego_batch).compile()
+            tracing.record_scopes(exe)
             self._ego_exes[ego_batch.sig] = exe
         return exe
 

@@ -216,18 +216,41 @@ def _aggregate_kernel(row_ref, slot_ref, src_ref, alpha_ref, h_ref, out_ref):
     out_ref[...] += _pick(a, lane, slot) * h_ref[...]
 
 
-def _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj):
+# names of the jitted wrappers below, which name their kernels' ops (_scoped)
+_FLAT = "fused_prune_aggregate_pallas"
+_GROUPED = "fused_prune_aggregate_grouped_pallas"
+
+
+def _scoped(stage: str, name: str, call):
+    """``call`` under the compile-time scope ``stage`` (``k1`` or ``k2``).
+
+    XLA names a custom call after the innermost scope, and a trace names a
+    device op by that instruction name alone; a kernel's op keeps the name
+    of its jitted wrapper ``name`` (the name trace readers match) with the
+    stage one level above it: ``op_name`` ``.../k1/<name>/pallas_call``,
+    instruction ``%<name>.N``.
+    """
+
+    def run(*operands):
+        with jax.named_scope(stage):
+            return jax.named_call(call, name=name)(*operands)
+
+    return run
+
+
+def _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj, name):
     """K2: Σ_slot α·h'[id] per output row, one retained row DMA per step.
 
     ``alpha`` is K1's (H, rows, K) output, ``ids`` its (rows, K) slot ids;
     ``agg_row``/``agg_slot`` (S,) list the (row, slot) pairs to gather, each
-    row's slots consecutive and starting at 0. Returns (rows, H, dh) f32.
+    row's slots consecutive and starting at 0; ``name`` is the calling
+    wrapper's (``_scoped``). Returns (rows, H, dh) f32.
     """
     h, rows, k = alpha.shape
     n, _, dh = h_proj.shape
     src = jnp.maximum(ids[agg_row, agg_slot], 0)  # α is 0 on empty slots
     DISPATCH["pallas_calls"] += 1
-    return pl.pallas_call(
+    return _scoped("k2", name, pl.pallas_call(
         _aggregate_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -246,7 +269,7 @@ def _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj):
         ),
         out_shape=jax.ShapeDtypeStruct((rows, h, dh), jnp.float32),
         interpret=common.interpret_mode(),
-    )(agg_row, agg_slot, src, alpha.transpose(1, 0, 2), h_proj.astype(jnp.float32))
+    ))(agg_row, agg_slot, src, alpha.transpose(1, 0, 2), h_proj.astype(jnp.float32))
 
 
 def _prune_scratch(t_tile: int, k: int, h: int):
@@ -277,7 +300,7 @@ def fused_prune_aggregate_pallas(
     tt, dd = mask.shape
 
     DISPATCH["pallas_calls"] += 1
-    alpha, ids = pl.pallas_call(
+    alpha, ids = _scoped("k1", _FLAT, pl.pallas_call(
         functools.partial(_prune_kernel, k_eff=k, slope=slope),
         grid=(tt // T_TILE, dd // D_TILE),
         in_specs=[
@@ -296,11 +319,11 @@ def fused_prune_aggregate_pallas(
         ],
         scratch_shapes=_prune_scratch(T_TILE, k, h),
         interpret=common.interpret_mode(),
-    )(theta_g.transpose(2, 0, 1), mask, gid, theta_dst)
+    ))(theta_g.transpose(2, 0, 1), mask, gid, theta_dst)
 
     agg_row = jnp.repeat(jnp.arange(tt, dtype=jnp.int32), k)
     agg_slot = jnp.tile(jnp.arange(k, dtype=jnp.int32), tt)
-    out = _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj)
+    out = _gather_aggregate(alpha, ids, agg_row, agg_slot, h_proj, _FLAT)
     return out[:t]
 
 
@@ -337,7 +360,7 @@ def fused_prune_aggregate_grouped_pallas(
     sq = pl.Squeezed()
 
     DISPATCH["pallas_calls"] += 1
-    alpha, ids = pl.pallas_call(
+    alpha, ids = _scoped("k1", _GROUPED, pl.pallas_call(
         functools.partial(_grouped_prune_kernel, slope=slope),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -359,7 +382,7 @@ def fused_prune_aggregate_grouped_pallas(
             jax.ShapeDtypeStruct((rows, k_s), jnp.int32),
         ],
         interpret=common.interpret_mode(),
-    )(
+    ))(
         meta,
         theta_g.astype(jnp.float32).transpose(0, 3, 1, 2),
         mask.astype(jnp.int32),
@@ -367,5 +390,5 @@ def fused_prune_aggregate_grouped_pallas(
         theta_dst_rows.astype(jnp.float32).reshape(rows, h),
     )
 
-    out = _gather_aggregate(alpha, ids, agg_meta[0], agg_meta[1], h_proj)
+    out = _gather_aggregate(alpha, ids, agg_meta[0], agg_meta[1], h_proj, _GROUPED)
     return out if perm is None else out[perm]

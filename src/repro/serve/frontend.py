@@ -49,6 +49,14 @@ stranded; every one resolves with a result or a typed error from
     at the checkout / dispatch / drain seams, so every failure mode
     above is deterministically testable on ``FakeClock`` +
     ``InlineExecutor`` with zero real sleeps (``benchmarks/serve_chaos``).
+
+TRACING — while a profiler trace runs, ``repro.tracing`` spans mark the
+collector (``serve.drain``, ``serve.pipe_put``), the stepper
+(``serve.dispatch``, ``serve.sync``, ``serve.complete``) and
+``serve.submit``. Every span of a block carries its ``block`` number; the
+dispatch span carries the block's queue- and pipe-wait sums and the
+complete span its service time, all on the serving clock, so each
+request's latency is queue wait + pipe wait + service.
 """
 from __future__ import annotations
 
@@ -59,6 +67,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.serve.clock import Clock, SystemClock, ThreadExecutor
 from repro.serve.faults import FaultContext, FaultPlan
 from repro.serve.health import (
@@ -93,7 +102,6 @@ class ServeStats:
     def __init__(self):
         self._lock = threading.Lock()
         self.latencies: List[float] = []
-        self.block_sizes: List[int] = []
         self.submitted = 0
         self.completed = 0
         self.blocks = 0
@@ -118,7 +126,6 @@ class ServeStats:
     def on_block(self, blk: QueryBlock, now: float, engine: str = "primary") -> None:
         with self._lock:
             self.blocks += 1
-            self.block_sizes.append(blk.n_valid)
             self.valid_slots += blk.n_valid
             self.padded_slots += blk.padded_slots
             self.completed += len(blk.requests)
@@ -176,9 +183,7 @@ class ServeStats:
             "p50_ms": self.percentile(50) * 1e3,
             "p99_ms": self.percentile(99) * 1e3,
             "qps": self.qps(),
-            "mean_batch": (
-                float(np.mean(self.block_sizes)) if self.block_sizes else 0.0
-            ),
+            "mean_batch": self.valid_slots / self.blocks if self.blocks else 0.0,
             "pad_fraction": self.pad_fraction,
             "shed": self.shed,
             "expired": self.expired,
@@ -186,6 +191,21 @@ class ServeStats:
             "retries": self.retries,
             "fallback_blocks": self.fallback_blocks,
         }
+
+
+def _wait_stats(blk: QueryBlock) -> Dict[str, float]:
+    """The ``serve.dispatch`` span's stats: the block's size and the sums
+    over its requests of queue wait (``t_packed - t_submit``) and pipe wait
+    (``t_dispatch - t_packed``), in microseconds of the serving clock."""
+    n = len(blk.requests)
+    queue_wait = sum(blk.t_packed - req.t_submit for req, _ in blk.requests)
+    return {
+        "capacity": blk.capacity,
+        "n_valid": blk.n_valid,
+        "requests": n,
+        "queue_wait_us_sum": queue_wait * 1e6,
+        "pipe_wait_us_sum": n * (blk.t_dispatch - blk.t_packed) * 1e6,
+    }
 
 
 class ServeFrontend:
@@ -306,6 +326,10 @@ class ServeFrontend:
         ``QueueFullError`` instead. ``timeout`` (seconds on the serving
         clock) sets the request's deadline — expired-in-queue requests
         fail with ``DeadlineExceededError`` at drain time."""
+        with tracing.span("serve.submit"):
+            return self._submit(targets, tenant, timeout)
+
+    def _submit(self, targets, tenant: str, timeout: Optional[float]) -> ServeFuture:
         if self._closed:
             raise RuntimeError("front-end is closed")
         if tenant not in self.plane:
@@ -450,43 +474,64 @@ class ServeFrontend:
         (injected or real) is caught and counted, the requests stay
         pending, and the next iteration retries — the collector never
         dies on one bad drain."""
-        try:
-            if self.faults is not None:
-                self.faults.fire("drain", self._ctx("drain"))
-            return self.queue.drain(
-                self.policy, self.clock.now(), force=force,
-                on_expired=self._on_expired,
-            )
-        except Exception as exc:  # noqa: BLE001 - supervisor boundary
-            self._collector_errors += 1
-            self._last_error = exc
-            return []
+        with tracing.span("serve.drain") as sp:
+            try:
+                if self.faults is not None:
+                    self.faults.fire("drain", self._ctx("drain"))
+                blocks = self.queue.drain(
+                    self.policy,
+                    self.clock.now(),
+                    force=force,
+                    on_expired=self._on_expired,
+                )
+            except Exception as exc:  # noqa: BLE001 - supervisor boundary
+                self._collector_errors += 1
+                self._last_error = exc
+                return []
+            if blocks and tracing.enabled():
+                sp.set_metadata(
+                    blocks=len(blocks),
+                    requests=sum(len(b.requests) for b in blocks),
+                )
+            return blocks
 
     def _resolve(self, staged) -> None:
         if staged is None:
             return
         blk, out, engine = staged
         try:
-            # repro: allow(serve-host-sync) -- THE sanctioned sync point
-            rows = np.asarray(jax.block_until_ready(out))
+            with tracing.span("serve.sync", block=blk.block):
+                # repro: allow(serve-host-sync) -- THE sanctioned sync point
+                rows = np.asarray(jax.block_until_ready(out))
         except Exception as exc:  # device failure surfaces at the sync
             self._fail_block(blk, exc)
             return
         # account BEFORE completing futures: a flush() waiting on the last
         # future must observe final stats the moment it unblocks
-        self.stats.on_block(blk, self.clock.now(), engine)
-        with self._outstanding_lock:
-            for req, _ in blk.requests:
-                self._outstanding.discard(req.future)
-        for req, slc in blk.requests:
-            req.future.set_result(rows[slc], via=engine)
+        now = self.clock.now()
+        self.stats.on_block(blk, now, engine)
+        with tracing.span("serve.complete", block=blk.block) as sp:
+            if tracing.enabled():
+                sp.set_metadata(
+                    requests=len(blk.requests),
+                    service_us=(now - blk.t_dispatch) * 1e6,
+                )
+            with self._outstanding_lock:
+                for req, _ in blk.requests:
+                    self._outstanding.discard(req.future)
+            for req, slc in blk.requests:
+                req.future.set_result(rows[slc], via=engine)
 
     def _step(self, blk: QueryBlock) -> None:
         """Double-buffered step: dispatch this block, then resolve the
         PREVIOUS one — its device work overlapped this dispatch. A block
         whose dispatch failed was already resolved (with an error) by the
         supervisor; the staged block stays staged."""
-        res = self._supervised_dispatch(blk)
+        blk.t_dispatch = self.clock.now()
+        with tracing.span("serve.dispatch", block=blk.block) as sp:
+            if tracing.enabled():
+                sp.set_metadata(**_wait_stats(blk))
+            res = self._supervised_dispatch(blk)
         if res is None:
             return
         out, engine = res
@@ -553,7 +598,9 @@ class ServeFrontend:
             seen = self.queue.version  # snapshot BEFORE draining
             blocks = self._drain_safe(force=stopping)
             for blk in blocks:
-                self._pipe.put(blk)  # bounded: backpressure to the queue
+                # bounded: backpressure to the queue
+                with tracing.span("serve.pipe_put", block=blk.block):
+                    self._pipe.put(blk)
             if stopping and len(self.queue) == 0:
                 self._pipe.put(None)
                 return

@@ -124,13 +124,23 @@ class QueryBlock:
     """One padded microbatch: ``idx`` has length ``capacity`` (a ladder
     capacity), rows ``[:n_valid]`` are real query ids in request order,
     padded slots repeat a valid id and are discarded. ``requests`` maps
-    each member request to its row slice of the block output."""
+    each member request to its row slice of the block output.
+
+    ``block`` is the queue's sequence number of the block and
+    ``t_packed`` the clock time of the drain that packed it;
+    ``t_dispatch`` is stamped by the front-end when it dispatches the
+    block. A request's latency splits into queue wait (``t_packed -
+    t_submit``), pipe wait (``t_dispatch - t_packed``) and service (from
+    ``t_dispatch`` to its resolution)."""
 
     tenant: str
     idx: np.ndarray
     requests: List[Tuple[Request, slice]]
     n_valid: int
     t_oldest: float
+    block: int = -1
+    t_packed: float = 0.0
+    t_dispatch: Optional[float] = None
 
     @property
     def capacity(self) -> int:
@@ -225,6 +235,7 @@ class RequestQueue:
         self._cond = threading.Condition()
         self._pending: List[Request] = []
         self._seq = 0
+        self._blocks = 0  # sequence number of the next packed block
 
     def __len__(self) -> int:
         with self._cond:
@@ -356,7 +367,7 @@ class RequestQueue:
                             saturated or force
                             or now - t_old >= policy.flush_timeout
                         ):
-                            blocks.append(self._pack(group, total, policy))
+                            blocks.append(self._pack(group, total, policy, now))
                             emitted.update(g.seq for g in group)
                     group, total = ([r], r.size) if r is not None else ([], 0)
             if emitted:
@@ -375,8 +386,9 @@ class RequestQueue:
                 ))
         return blocks
 
-    @staticmethod
-    def _pack(group: List[Request], total: int, policy: BatchPolicy) -> QueryBlock:
+    def _pack(
+        self, group: List[Request], total: int, policy: BatchPolicy, now: float
+    ) -> QueryBlock:
         cap = policy.capacity_for(total)
         idx = np.empty(cap, np.int32)
         requests: List[Tuple[Request, slice]] = []
@@ -386,7 +398,10 @@ class RequestQueue:
             requests.append((r, slice(off, off + r.size)))
             off += r.size
         idx[off:] = idx[0]  # pad with a valid id; rows are discarded
-        return QueryBlock(
+        blk = QueryBlock(
             tenant=group[0].tenant, idx=idx, requests=requests,
             n_valid=off, t_oldest=group[0].t_submit,
+            block=self._blocks, t_packed=float(now),
         )
+        self._blocks += 1
+        return blk

@@ -134,3 +134,36 @@ def test_sharded_body_compiles_for_v5e(dblp, topo, mosaic):
     compiled = fn.lower(*args).compile()
     _assert_mosaic(compiled)
     assert "num_partitions=4" in compiled.as_text()
+
+
+def test_stage_scopes_keep_the_kernel_names_for_v5e(dblp, one_chip, mosaic):
+    """One semantic graph's NA through ``flows.run_aggregate_graph``: the
+    Mosaic calls keep their wrapper's instruction name (a trace names an op
+    by it), and their ``op_name`` carries ``na.<graph>`` over ``k1`` /
+    ``k2``, as ``repro.tracing.record_scopes`` reads it."""
+    import re
+
+    from repro import tracing
+    from repro.core import attention, flows
+
+    sg = dblp.sgs[0]
+    n = dblp.graph.num_nodes["author"]
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    cfg = flows.FlowConfig("fused_kernel", prune_k=K, shard="off")
+
+    def na(h, ts, td):
+        scores = attention.DecomposedScores(ts, td, None)
+        return flows.run_aggregate_graph(cfg, h, scores, sg)
+
+    compiled = jax.jit(na).lower(f32(n, H, DH), f32(n, H), f32(sg.num_targets, H)).compile()
+    _assert_mosaic(compiled)
+    tracing.record_scopes(compiled)
+    calls = re.findall(r"%(\S+) = \S.* custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text())
+    assert len(calls) == 2
+    scopes = tracing.op_scopes()["jit_na"]
+    got = sorted(scopes[c].split("/")[-4:-1] for c in calls)
+    assert all(c.startswith("fused_prune_aggregate_grouped_pallas.") for c in calls)
+    assert got == [["jit(fused_prune_aggregate_grouped_pallas)", k,
+                    "fused_prune_aggregate_grouped_pallas"] for k in ("k1", "k2")]
+    assert all(f"/na.{sg.name}/" in scopes[c] for c in calls)
