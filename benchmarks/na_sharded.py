@@ -48,6 +48,7 @@ from repro.core import flows, pipeline
 from repro.core.attention import DecomposedScores
 from repro.core.flows import FlowConfig, run_aggregate_graph
 from repro.kernels.fused_prune_aggregate import kernel as fpa_kernel
+from repro.kernels.fused_prune_aggregate.ops import tile_shape
 
 BUCKETS = (4, 8, 16, 32)
 HEADS, DH = 4, 8
@@ -128,11 +129,12 @@ def bench_model(model: str, size: str, scale: float):
             # padded-slot balance of the row-block partition
             balances, slots_all = [], []
             for sg, _ in per_sg:
-                sl = sg.sharded(ways)
+                shape = tile_shape(sg)
+                sl = sg.sharded(ways, *shape)
                 balances.append(sl.balance())
                 slots_all.append(sl.padded_slots())
                 max_block = (
-                    int(sg.grouped().step_ndt.max(initial=1))
+                    int(sg.grouped(*shape).step_ndt.max(initial=1))
                     * sl.t_tile * sl.w
                 )
                 slots = sl.padded_slots()

@@ -45,6 +45,7 @@ import numpy as np  # noqa: E402
 from repro import compile_cache  # noqa: E402
 from repro.core import pipeline  # noqa: E402
 from repro.core.flows import FlowConfig  # noqa: E402
+from repro.kernels.fused_prune_aggregate.ops import tile_shape  # noqa: E402
 from repro.serve import (  # noqa: E402
     BatchPolicy,
     InlineExecutor,
@@ -103,7 +104,7 @@ def _graph_summary(task) -> None:
           f"features {({t: x.shape[1] for t, x in g.features.items()})}",
           flush=True)
     for sg in task.sgs:
-        lay = sg.grouped()
+        lay = sg.grouped(*tile_shape(sg))
         buckets = [(b.num_targets, b.capacity) for b in sg.buckets]
         print(f"[graph] semantic graph {sg.name}: {sg.num_targets} targets, "
               f"{sg.num_edges} edges, buckets (targets, capacity) {buckets}, "
@@ -254,7 +255,7 @@ def four_chips(args, check, on_tpu) -> None:
             # each device's K1 launch reads its own shard of the stacked
             # (4, 5, G) step metadata: a (1, 5, G) slice in the partitioned
             # HLO; G is the longest shard's steps plus one pad-block step
-            g = sg.sharded(4).num_steps_max + 1
+            g = sg.sharded(4, *tile_shape(sg)).num_steps_max + 1
             local, whole = f"s32[1,5,{g}]" in hlo, f"s32[4,5,{g}]" in hlo
             print(f"[mesh] {sg.name}: shard-local K1 metadata s32[1,5,{g}] in "
                   f"the partitioned program: {local}; whole 4-shard stack also "

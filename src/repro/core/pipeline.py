@@ -251,16 +251,16 @@ def prepare(
     params = model.init(key, spec)
 
     if shards:
-        # the kernel's tile constants, not hetgraph's generic defaults: the
-        # sharded dispatch keys its layout cache on (n, T_TILE, W_TILE), so
-        # pre-splitting with anything else would build a split no dispatch
-        # ever reads. On a cache hit build_or_load already injected the
-        # split; this is a no-op then (cached per layout).
-        from repro.kernels.fused_prune_aggregate.kernel import T_TILE, W_TILE
+        # the kernel's tile shape, not hetgraph's generic defaults: the
+        # sharded dispatch keys its layout cache on (n, *tile_shape(sg)),
+        # so pre-splitting with anything else would build a split no
+        # dispatch ever reads. On a cache hit build_or_load already
+        # injected the split; this is a no-op then (cached per layout).
+        from repro.kernels.fused_prune_aggregate.ops import tile_shape
 
         for sg in sgs:
             if isinstance(sg, hetgraph.BucketedSemanticGraph):
-                sg.sharded(shards, T_TILE, W_TILE)
+                sg.sharded(shards, *tile_shape(sg))
 
     return HGNNTask(
         name=f"{model_name}/{ds_name}",

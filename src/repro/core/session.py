@@ -147,9 +147,10 @@ class InferenceSession:
         self._jitted = jax.jit(
             fn, donate_argnums=(0,) if donate_params else ()
         )
-        self.lowered = self._jitted.lower(params)
+        with tracing.counting() as sites:
+            self.lowered = self._jitted.lower(params)
         self._executable = self.lowered.compile()
-        tracing.record_scopes(self._executable)
+        tracing.record_scopes(self._executable, sites)
         # query-sliced serving state: the output aval (shape/dtype AND
         # sharding, so gather programs accept the executable's committed
         # output under a mesh) plus one cached gather program per block
@@ -283,8 +284,10 @@ class InferenceSession:
                 with flows.mesh_scope(pinned=None):
                     return _gather(model.apply(p, b, flow), b.out_rows)
 
-            exe = jax.jit(fn).lower(params, ego_batch).compile()
-            tracing.record_scopes(exe)
+            with tracing.counting() as sites:
+                lowered = jax.jit(fn).lower(params, ego_batch)
+            exe = lowered.compile()
+            tracing.record_scopes(exe, sites)
             self._ego_exes[ego_batch.sig] = exe
         return exe
 

@@ -44,7 +44,7 @@ from repro.core.hetgraph import (
     ShardedBucketLayout,
 )
 
-CACHE_VERSION = 1
+CACHE_VERSION = 2
 
 KINDS = ("metapath", "relation", "union")
 
@@ -58,12 +58,21 @@ def default_cache_dir() -> Optional[Path]:
     return Path(env) if env else None
 
 
-def _tile_constants() -> Tuple[int, int]:
-    """The grouped kernel's tile shape — what the sharded dispatch keys its
-    layout cache on."""
-    from repro.kernels.fused_prune_aggregate.kernel import T_TILE, W_TILE
+def _tile_shape(sg: BucketedSemanticGraph) -> Tuple[int, int]:
+    """The grouped kernel's tile shape for ``sg`` — what the grouped and
+    sharded dispatch key their layout caches on."""
+    from repro.kernels.fused_prune_aggregate import ops
 
-    return int(T_TILE), int(W_TILE)
+    return ops.tile_shape(sg)
+
+
+def _tile_rule() -> dict:
+    """What ``_tile_shape`` picks from: the part of a cache key that
+    decides which layouts an entry carries."""
+    from repro.kernels.fused_prune_aggregate import ops
+
+    return {"t_tile": ops.T_TILE, "widths": list(ops.WIDTHS),
+            "c_step_us": ops.C_STEP_US, "c_slot_us": ops.C_SLOT_US}
 
 
 def graph_fingerprint(g: HetGraph) -> str:
@@ -118,10 +127,11 @@ def cache_key(
     max_degree: Optional[int] = None,
     seed: int = 0,
     bucket_sizes: Union[Sequence[int], str, None] = None,
-    t_tile: int = 8,
-    w: int = 8,
+    tile: Union[Tuple[int, int], dict, None] = None,
 ) -> str:
-    """Content address of one SGB artifact."""
+    """Content address of one SGB artifact; ``tile`` is the one tile shape
+    of its grouped layouts, or the rule that picks each graph's
+    (``_tile_rule``)."""
     if kind not in KINDS:
         raise ValueError(f"kind must be one of {KINDS}, got {kind!r}")
     params = {
@@ -135,8 +145,7 @@ def cache_key(
             bucket_sizes if isinstance(bucket_sizes, str)
             else list(bucket_sizes) if bucket_sizes is not None else None
         ),
-        "t_tile": t_tile,
-        "w": w,
+        "tile": list(tile) if isinstance(tile, tuple) else tile,
         "cache_version": CACHE_VERSION,
     }
     h = hashlib.blake2b(digest_size=16)
@@ -281,13 +290,13 @@ def save_sgb(
     sgs: Sequence[BucketedSemanticGraph],
     *,
     keys: Optional[Sequence[str]] = None,
-    t_tile: int = 8,
-    w: int = 8,
+    tile: Optional[Tuple[int, int]] = None,
     shards: Union[int, Sequence[int]] = (),
 ) -> Path:
-    """Serialize a bucketed-SGB stack (+ grouped layouts at ``(t_tile, w)``,
-    + one sharded split per entry of ``shards`` — an entry can carry splits
-    for several mesh sizes at once) to one npz. ``keys`` records dict
+    """Serialize a bucketed-SGB stack (+ each graph's grouped layout at
+    ``tile``, by default its own ``_tile_shape``, + one sharded split per
+    entry of ``shards`` — an entry can carry splits for several mesh sizes
+    at once) to one npz. ``keys`` records dict
     ordering for union builds. Atomic (tmp + ``os.replace``) so concurrent
     readers never see a torn entry."""
     path = Path(path)
@@ -311,6 +320,7 @@ def save_sgb(
             bw.add(f"{p}.nbr", b.nbr_idx)
             bw.add(f"{p}.msk", b.nbr_mask)
             bw.add(f"{p}.ety", b.edge_type)
+        t_tile, w = tile if tile is not None else _tile_shape(sg)
         m["grouped"] = _pack_grouped(f"s{i}.g", sg.grouped(t_tile, w), bw)
         splits = []
         for n in shard_ns:
@@ -331,8 +341,6 @@ def save_sgb(
     arrays, keymap = bw.blobs()
     meta = {
         "cache_version": CACHE_VERSION,
-        "t_tile": t_tile,
-        "w": w,
         "shards": shard_ns,
         "keys": list(keys) if keys is not None else None,
         "sgs": metas,
@@ -397,7 +405,6 @@ def _reconstruct_sgb(
             f"{path}: cache_version {meta.get('cache_version')!r} "
             f"unsupported"
         )
-    t_tile, w = int(meta["t_tile"]), int(meta["w"])
     br = _BlobReader(z, meta["arrays"], meta["blobs"])
     out: List[BucketedSemanticGraph] = []
     for i, m in enumerate(meta["sgs"]):
@@ -421,9 +428,9 @@ def _reconstruct_sgb(
             num_edge_types=int(m["num_edge_types"]),
         )
         sg.target_perm()
-        sg._grouped[(t_tile, w)] = _unpack_grouped(
-            f"s{i}.g", m["grouped"], br
-        )
+        lay = _unpack_grouped(f"s{i}.g", m["grouped"], br)
+        t_tile, w = lay.t_tile, lay.w
+        sg._grouped[(t_tile, w)] = lay
         for sh in m.get("sharded", ()):
             n = int(sh["n_shards"])
             sg._sharded[(n, t_tile, w)] = ShardedBucketLayout(
@@ -491,7 +498,7 @@ def build_or_load(
     bucket/grouped stacks were loaded, not rebuilt), so later processes
     on the same mesh load it precomputed.
     """
-    t_tile, w = tile if tile is not None else _tile_constants()
+    shape = (lambda sg: tile) if tile is not None else _tile_shape
     if cache_dir is None:
         cache_dir = default_cache_dir()
     if cache_dir is None or bucket_sizes is None:
@@ -499,7 +506,7 @@ def build_or_load(
         return out, "off"
     key = cache_key(
         g, kind, metapaths=metapaths, max_degree=max_degree, seed=seed,
-        bucket_sizes=bucket_sizes, t_tile=t_tile, w=w,
+        bucket_sizes=bucket_sizes, tile=tile if tile is not None else _tile_rule(),
     )
     path = Path(cache_dir) / f"sgb_{key}.npz"
     if path.is_file():
@@ -509,7 +516,7 @@ def build_or_load(
             sgs = None  # torn/stale entry: rebuild and overwrite below
         if sgs is not None:
             if shards > 0 and any(
-                (shards, t_tile, w) not in sg._sharded for sg in sgs
+                (shards, *shape(sg)) not in sg._sharded for sg in sgs
             ):
                 # upgrade in place: build the missing split, then merge
                 # into a FRESH read of the entry before re-saving — a
@@ -518,24 +525,19 @@ def build_or_load(
                 # (last-writer-wins in the remaining ~ms window costs at
                 # most one redundant rebuild later, never corruption)
                 for sg in sgs:
-                    sg.sharded(shards, t_tile, w)
+                    sg.sharded(shards, *shape(sg))
                 try:
                     fresh, fkeys = load_sgb(path)
                 except Exception:
                     fresh, fkeys = sgs, keys
                 for sg_f, sg_m in zip(fresh, sgs):
-                    sg_f._sharded.setdefault(
-                        (shards, t_tile, w),
-                        sg_m._sharded[(shards, t_tile, w)],
-                    )
+                    split = (shards, *shape(sg_m))
+                    sg_f._sharded.setdefault(split, sg_m._sharded[split])
                 all_ns = sorted({
                     k[0] for sg in fresh for k in sg._sharded
-                    if k[1:] == (t_tile, w)
+                    if k[1:] == shape(sg)
                 })
-                save_sgb(
-                    path, fresh, keys=fkeys, t_tile=t_tile, w=w,
-                    shards=all_ns,
-                )
+                save_sgb(path, fresh, keys=fkeys, tile=tile, shards=all_ns)
             out = dict(zip(keys, sgs)) if keys is not None else sgs
             return out, "hit"
     out = _build(g, kind, metapaths, max_degree, seed, bucket_sizes)
@@ -547,9 +549,9 @@ def build_or_load(
     # process) carries them precomputed
     for sg in sgs:
         if isinstance(sg, BucketedSemanticGraph):
-            sg.grouped(t_tile, w)
+            sg.grouped(*shape(sg))
             if shards > 0:
-                sg.sharded(shards, t_tile, w)
+                sg.sharded(shards, *shape(sg))
     if all(isinstance(sg, BucketedSemanticGraph) for sg in sgs):
-        save_sgb(path, sgs, keys=keys, t_tile=t_tile, w=w, shards=shards)
+        save_sgb(path, sgs, keys=keys, tile=tile, shards=shards)
     return out, "miss"

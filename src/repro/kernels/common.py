@@ -5,9 +5,6 @@ import jax
 import jax.numpy as jnp
 
 NEG = -3.0e38  # python float: below any real score, safe to capture in kernels
-POS = 3.0e38  # above any real score: parks retention-domain slots past a
-# row's effective K so min_replace never selects them (grouped ragged grid
-# shares one scratch width across buckets with different per-bucket K)
 
 
 def interpret_mode() -> bool:

@@ -4,7 +4,7 @@ Two chained Pallas kernels inside one jit region (mirroring the ASIC's
 pruner → aggregation-engine pipeline through the attention/edge buffers):
 
 K1  ``prune``: streams per-edge decomposed coefficients θ_u* (+ relation
-    term) in neighbor tiles, maintains the K-slot retention domain (ranking
+    term) in neighbor tiles, keeps each row's retained top K (ranking
     scalar, per-head θ vector, slot id) in VMEM scratch, and at the last
     tile applies LeakyReLU(θ_u*+θ_*v), masks, and softmaxes over the
     retained set — emitting attention weights α (H,T,K) and slot ids (T,K).
@@ -23,8 +23,19 @@ materialized anywhere.
 Layout, chosen so Mosaic compiles it: every tile keeps targets in sublanes
 and neighbor slots in lanes, and per-head arrays carry the head as a
 leading axis (θ tiles (H, Tt, W), retained θ (H, Tt, K), α (H, T, K)). A
-tile's column is read with a masked lane max, never a dynamic lane slice.
-K2's per-step scalars are three 1-D vectors (row, slot, source id).
+row's element is read with a masked lane max (``_pick``), never a dynamic
+lane slice. K2's per-step scalars are three 1-D vectors (row, slot,
+source id).
+
+K1's merge: a grid step holds a row block's retained (Tt, K) and one
+candidate tile (Tt, W), and runs K rounds, however wide the tile. A round
+takes each row's largest rank over [retained | tile] at its first index
+(retained before tile, both in slot/column order), stores the winner in the
+round's slot and masks it out; a masked slot (rank ``NEG``) is never
+taken. Retained slots are earlier winners in round order, so equal ranks
+sit in arrival order: the kept set is ``lax.top_k``'s with first-arrival
+ties (``ref.py``). A step's serial work is K rounds, not W insertions, so a
+lane-wide tile (W up to 128) costs about what a W=8 tile did.
 
 Two grid shapes share the K1 body and the K2 kernel:
 
@@ -33,16 +44,16 @@ Two grid shapes share the K1 body and the K2 kernel:
   * **grouped ragged** (``fused_prune_aggregate_grouped_pallas``): every
     degree bucket of a ``BucketedSemanticGraph`` in ONE launch. The 1-D
     grid walks a ``GroupedBucketLayout``'s tile stack (bucket-major,
-    row-tile next, D-tile innermost); a scalar-prefetched metadata table
-    tells each step its output row block, its D-tile position (first →
-    reset scratch, last → softmax + flush), its bucket's effective K, and
-    whether the bucket takes the §4.3 pruner **bypass** branch
-    (capacity ≤ K: candidate tiles are copied straight into their
-    statically-known retention slots — no min-replace scan). Buckets with
-    different capacities share one scratch of width K_s = max effective K;
-    slots past a row's own K are parked at +inf (``POS``) so the
-    retention-domain argmin never selects them. Narrow buckets therefore
-    run fewer D-tile steps instead of padding to the global D_max, and the
+    row-tile next, D-tile innermost) at the width the caller picked for the
+    graph (``ops.tile_shape``); a scalar-prefetched metadata table tells
+    each step its output row block, its D-tile position (first → reset
+    scratch, last → softmax + flush), its bucket's effective K
+    (min(K, capacity)), and whether the bucket takes the §4.3 pruner
+    **bypass** branch (capacity ≤ K: the tile's first K columns are copied
+    straight into the retained slots — no merge). Buckets with different
+    capacities share one scratch of width K_s = max effective K; slots past
+    a row's own K are dropped at the flush. Narrow buckets therefore run
+    fewer D-tile steps instead of padding to the global D_max, and the
     whole semantic graph costs one ``pallas_call`` pair instead of one per
     bucket.
 """
@@ -57,14 +68,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
-from repro.kernels.common import NEG, POS, argmin_onehot
+from repro.kernels.common import NEG
 
 T_TILE = 8
 D_TILE = 128
-# grouped ragged grid: D-tile width. Narrow so capacity-8/16/32 buckets pay
-# at most w-1 padded slots per row, at the price of K1 tiles that fill 8 of
-# a vreg's 128 lanes.
-W_TILE = 8
 
 # trace-time launch accounting: how many pallas_call sites were traced and
 # how often the grouped single-dispatch region retraced. After
@@ -75,19 +82,26 @@ W_TILE = 8
 DISPATCH = {"pallas_calls": 0, "grouped_traces": 0, "sharded_traces": 0}
 
 
-def _pick(x: jax.Array, lane: jax.Array, j) -> jax.Array:
-    """Column ``j`` of ``x`` along its lane (last) axis, kept as a size-1
+def _pick(x: jax.Array, hit: jax.Array) -> jax.Array:
+    """Per row, the element of ``x`` under the one-hot lane mask ``hit``
+    (the dtype's least value where no lane hits), kept as a size-1 lane
     axis. A masked max, exact for every value: Mosaic has no dynamic lane
-    slice, and this serves a traced ``j`` and a static one alike."""
+    slice, and this serves a traced column and a static one alike."""
     floating = np.issubdtype(x.dtype, np.floating)
     fill = -np.inf if floating else np.iinfo(x.dtype).min
-    return jnp.max(jnp.where(lane == j, x, fill), axis=-1, keepdims=True)
+    return jnp.max(jnp.where(hit, x, fill), axis=-1, keepdims=True)
+
+
+def _first(hit: jax.Array, idx: jax.Array, none: int) -> jax.Array:
+    """Per row, the least ``idx`` where ``hit`` (``none`` where no lane
+    hits), kept as a size-1 lane axis."""
+    return jnp.min(jnp.where(hit, idx, none), axis=-1, keepdims=True)
 
 
 def _prune_body(
     dt,  # D-tile index of this step within its row block
     n_dt,  # D-tiles of the row block
-    k_eff,  # effective K of the row block; slots past it park at +inf
+    k_eff,  # effective K of the row block; slots past it are dropped
     bypass,  # §4.3 direct-copy flag, or None where no step can set it
     theta_ref,  # (H, Tt, W) θ_u* (+rel) per edge slot, head-major
     mask_ref,  # (Tt, W) int32
@@ -101,13 +115,18 @@ def _prune_body(
     *,
     slope: float,
 ):
-    """K1 on one (Tt, W) tile: Algorithm-1 retention-domain update, and at
-    the row block's last D-tile the masked LeakyReLU softmax over it.
+    """K1 on one (Tt, W) tile: merge the tile into each row's retained
+    top K, and at the row block's last D-tile the masked LeakyReLU softmax
+    over it.
 
-    Every array keeps targets in sublanes and slots in lanes; per-head
-    arrays carry the head as a leading axis. The column walk is a
-    ``fori_loop`` whose candidate column is a masked lane max (``_pick``),
-    so no step needs a dynamic lane slice — the layout Mosaic compiles."""
+    The merge runs K rounds, however wide the tile. Each round takes every
+    row's largest rank over [retained | tile] (its first index: retained
+    slots before tile columns), writes that winner's rank, id and per-head
+    θ to the round's slot, and masks it out. The retained slots hold an
+    earlier round's winners in round order, so among equal ranks they are
+    in arrival order and precede the tile's later columns: the kept set is
+    ``lax.top_k``'s with first-arrival ties (``ref.py``). Masked slots
+    (rank ``NEG``) are never taken."""
     h, t_tile, w = theta_ref.shape
     k = rd_rank.shape[-1]
     slot = jax.lax.broadcasted_iota(jnp.int32, (t_tile, k), 1)
@@ -115,7 +134,7 @@ def _prune_body(
 
     @pl.when(dt == 0)
     def _init():
-        rd_rank[...] = jnp.where(slot < k_eff, NEG, POS)
+        rd_rank[...] = jnp.full_like(rd_rank, NEG)
         rd_theta[...] = jnp.zeros_like(rd_theta)
         rd_id[...] = jnp.full_like(rd_id, -1)
 
@@ -124,47 +143,57 @@ def _prune_body(
     rank = jnp.where(valid, theta.sum(0), NEG)  # (Tt, W)
     gids = jnp.where(valid, gid_ref[...], -1)
 
-    def walk(update):
-        def step(j, carry):
-            rr, ri, rt = carry
-            cur = _pick(rank, col, j)  # (Tt, 1)
-            at = update(j, rr, cur)  # (Tt, K) slot receiving column j
+    def merge():
+        kept_id, kept_th = rd_id[...], rd_theta[...]
+
+        def round_(r, carry):
+            kept, cand, nr, ni, nt = carry
+            m_k = jnp.max(kept, axis=-1, keepdims=True)  # (Tt, 1)
+            m_c = jnp.max(cand, axis=-1, keepdims=True)
+            from_kept = m_k >= m_c  # ties go to the earlier arrival
+            m = jnp.maximum(m_k, m_c)
+            hit_k = from_kept & (slot == _first(kept == m, slot, k))
+            hit_c = ~from_kept & (col == _first(cand == m, col, w))
+            at = (slot == r) & (m > NEG / 2)
             return (
-                jnp.where(at, cur, rr),
-                jnp.where(at, _pick(gids, col, j), ri),
-                jnp.where(at[None], _pick(theta, col[None], j), rt),
+                jnp.where(hit_k, -jnp.inf, kept),
+                jnp.where(hit_c, -jnp.inf, cand),
+                jnp.where(at, m, nr),
+                jnp.where(
+                    at, jnp.maximum(_pick(kept_id, hit_k), _pick(gids, hit_c)), ni
+                ),
+                jnp.where(
+                    at[None],
+                    jnp.maximum(_pick(kept_th, hit_k[None]), _pick(theta, hit_c[None])),
+                    nt,
+                ),
             )
 
-        rr, ri, rt = jax.lax.fori_loop(
-            0, w, step, (rd_rank[...], rd_id[...], rd_theta[...])
+        _, _, nr, ni, nt = jax.lax.fori_loop(
+            0, k, round_,
+            (rd_rank[...], rank, jnp.full_like(rd_rank, NEG),
+             jnp.full_like(rd_id, -1), jnp.zeros_like(rd_theta)),
         )
-        rd_rank[...] = rr
-        rd_id[...] = ri
-        rd_theta[...] = rt
+        rd_rank[...] = nr
+        rd_id[...] = ni
+        rd_theta[...] = nt
 
-    def replace_min(j, rr, cur):
-        # strict '>' keeps the incumbent on ties; the FIRST minimum goes
-        onehot, m = argmin_onehot(rr)
-        return onehot & (cur > m)
-
-    # static guard: a bypass bucket's k_eff is its padded capacity (≥ w), so
-    # K < w proves no step sets the flag — and the w slots the copy fills
-    # would not fit the scratch (pl.when still traces untaken branches)
-    if bypass is not None and k >= w:
+    # static guard: a bypass bucket holds at most K ≤ k candidates, so
+    # where k ≤ w they all sit in the first k columns of its one D-tile
+    if bypass is not None and k <= w:
 
         @pl.when(bypass != 0)
         def _direct():
             # §4.3 pruner bypass, in-kernel: capacity ≤ K means every
-            # candidate is retained, so its slot is known statically from
-            # the tile column — a straight copy, no argmin per column
-            walk(lambda j, rr, cur: slot == dt * w + j)
+            # candidate is retained, each in the slot of its column
+            rd_rank[...] = rank[:, :k]
+            rd_id[...] = gids[:, :k]
+            rd_theta[...] = theta[:, :, :k]
 
-        @pl.when(bypass == 0)
-        def _insert():
-            walk(replace_min)
+        pl.when(bypass == 0)(merge)
 
     else:
-        walk(replace_min)
+        merge()
 
     @pl.when(dt == n_dt - 1)
     def _flush():
@@ -173,8 +202,8 @@ def _prune_body(
         cube = (h, t_tile, h)
         th_dst = _pick(
             jnp.broadcast_to(theta_dst_ref[...][None], cube),
-            jax.lax.broadcasted_iota(jnp.int32, cube, 2),
-            jax.lax.broadcasted_iota(jnp.int32, cube, 0),
+            jax.lax.broadcasted_iota(jnp.int32, cube, 2)
+            == jax.lax.broadcasted_iota(jnp.int32, cube, 0),
         )
         th = rd_theta[...] + th_dst  # (H, Tt, K)
         th = jnp.where(th >= 0, th, slope * th)  # LeakyReLU
@@ -213,7 +242,7 @@ def _aggregate_kernel(row_ref, slot_ref, src_ref, alpha_ref, h_ref, out_ref):
 
     a = alpha_ref[...]  # (H, K)
     lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    out_ref[...] += _pick(a, lane, slot) * h_ref[...]
+    out_ref[...] += _pick(a, lane == slot) * h_ref[...]
 
 
 # names of the jitted wrappers below, which name their kernels' ops (_scoped)
@@ -338,8 +367,8 @@ def fused_prune_aggregate_grouped_pallas(
     h_proj: jax.Array,  # (N, H, dh)
     perm: jax.Array,  # (T,) grouped row of each target; None = raw rows
     k_s: int,
-    t_tile: int = T_TILE,
-    w: int = W_TILE,
+    t_tile: int,
+    w: int,
     slope: float = 0.2,
 ) -> jax.Array:
     """Single-launch NA over all buckets of a grouped layout.

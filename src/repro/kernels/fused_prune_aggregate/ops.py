@@ -8,7 +8,9 @@ same grouped grid partitioned across a device mesh — ONE kernel pair *per
 shard* under ``shard_map``, each shard walking only its own row blocks of
 the ``ShardedBucketLayout``, with θ_*v gathers local to the shard and one
 all-gather of the per-shard outputs before the global inverse-permutation
-gather restores target order. Device mirrors of a graph's static tile
+gather restores target order. Both grouped paths tile a graph at the
+width ``tile_shape`` reads from its buckets, so the sharded launch stays
+bit-identical to the single-device one. Device mirrors of a graph's static tile
 stack and the per-``prune_k`` metadata tables are cached on its
 ``GroupedBucketLayout`` / ``ShardedBucketLayout`` so repeated layers/steps
 ship no host arrays.
@@ -22,14 +24,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.distributed import sharding as dist
 from repro.kernels.fused_prune_aggregate.kernel import (
+    D_TILE,
     DISPATCH,
     T_TILE,
-    W_TILE,
     fused_prune_aggregate_grouped_pallas,
     fused_prune_aggregate_pallas,
 )
+
+# The grouped layout's D-tile width, chosen per semantic graph from its
+# buckets (``tile_shape``): the width of WIDTHS that minimises
+# C_STEP_US·steps + C_SLOT_US·slots, K1's grid steps and the padded slots
+# the θ gathers and tile transposes before it move. Both constants were
+# measured on a TPU v5e (benchmarks/k1_tile_sweep.py): a merge step costs
+# 3.3-3.6 µs at every width; a padded slot 2.7 ns of glue on HAN DBLP and
+# 6.8 ns with Simple-HGN ACM's edge-type add, whose mean is taken.
+WIDTHS = (8, 16, 32, 64, 128)
+C_STEP_US = 3.4  # one K1 grid step: K merge rounds, whatever the width
+C_SLOT_US = 0.0045  # one padded edge slot of NA glue (θ gather, transpose)
 
 
 def fused_prune_aggregate(
@@ -43,27 +57,42 @@ def fused_prune_aggregate(
     prune_k: Optional[int] = None,
     slope: float = 0.2,
 ) -> jax.Array:
-    # The scalar pass: θ_u* per edge slot. 4·H bytes/edge instead of the
-    # 4·H·dh bytes/edge feature row the staged flow gathers.
-    theta_g = theta_src[nbr_idx]
-    if theta_rel is not None and edge_type is not None:
-        theta_g = theta_g + theta_rel[edge_type]
-    k = prune_k if prune_k is not None else nbr_idx.shape[1]
-    return fused_prune_aggregate_pallas(
-        theta_g, nbr_mask, theta_dst, nbr_idx, h_proj,
-        prune_k=k, slope=slope,
-    )
+    t, d = nbr_idx.shape
+    k = prune_k if prune_k is not None else d
+    with tracing.counted("k1_steps", -(-t // T_TILE) * -(-d // D_TILE)):
+        # The scalar pass: θ_u* per edge slot. 4·H bytes/edge instead of
+        # the 4·H·dh bytes/edge feature row the staged flow gathers.
+        theta_g = theta_src[nbr_idx]
+        if theta_rel is not None and edge_type is not None:
+            theta_g = theta_g + theta_rel[edge_type]
+        return fused_prune_aggregate_pallas(
+            theta_g, nbr_mask, theta_dst, nbr_idx, h_proj,
+            prune_k=k, slope=slope,
+        )
+
+
+def tile_shape(sg, t_tile: int = T_TILE) -> tuple:
+    """``(t_tile, w)`` of ``sg``'s grouped layout: among ``WIDTHS``, the
+    width of least ``C_STEP_US·steps + C_SLOT_US·slots``, counted from the
+    buckets' row blocks and capacities (the narrower width on a tie)."""
+    blocks = np.array([-(-b.num_targets // t_tile) for b in sg.buckets], np.int64)
+    caps = np.array([b.capacity for b in sg.buckets], np.int64)
+
+    def cost(w):
+        steps = int((blocks * np.maximum(-(-caps // w), 1)).sum())
+        return C_STEP_US * steps + C_SLOT_US * steps * t_tile * w
+
+    return t_tile, min(WIDTHS, key=cost)
 
 
 def grouped_meta(layout, prune_k: Optional[int]):
     """Per-grid-step metadata + scratch width for a grouped launch.
 
-    ``k_eff`` per bucket is ``prune_k`` when the bucket is pruned and the
-    w-aligned capacity when it takes the §4.3 bypass (capacity ≤ prune_k,
-    or no pruning at all) — the bypass branch copies candidates into
-    statically-known slots, so it needs the full padded width. The shared
-    scratch width ``k_s`` is the max effective K across buckets that
-    actually contribute grid steps (empty buckets don't widen anything).
+    ``k_eff`` per bucket is ``min(prune_k, capacity)`` (the capacity where
+    there is no pruning), whatever the tile width; buckets with capacity ≤
+    ``prune_k`` (all, unpruned) take the §4.3 bypass. The shared scratch
+    width ``k_s`` is the max effective K across buckets that actually
+    contribute grid steps (empty buckets don't widen anything).
 
     Returns ``(k1_meta, k2_meta, k_s)``: K1 rows are (row_block, dt, n_dt,
     bypass, k_eff) per prune step; K2 rows are (grouped_row, slot) per
@@ -71,13 +100,12 @@ def grouped_meta(layout, prune_k: Optional[int]):
     k_eff steps, so the ragged gather never pays the shared width.
     """
     caps = layout.caps.astype(np.int64)
-    caps_pad = layout.caps_pad.astype(np.int64)
     if prune_k is None:
         bypass = np.ones_like(caps)
-        k_eff = caps_pad
+        k_eff = caps
     else:
         bypass = (caps <= prune_k).astype(np.int64)
-        k_eff = np.where(bypass, caps_pad, np.minimum(prune_k, caps_pad))
+        k_eff = np.minimum(prune_k, caps)
     present = np.unique(layout.step_bucket)
     k_s = int(k_eff[present].max()) if len(present) else 1
     meta = np.stack(
@@ -156,13 +184,13 @@ def fused_prune_aggregate_grouped(
     theta_rel: Optional[jax.Array] = None,  # (R, H)
     prune_k: Optional[int] = None,
     slope: float = 0.2,
-    t_tile: int = T_TILE,
-    w: int = W_TILE,
 ) -> jax.Array:
-    """NA over ALL buckets of ``sg`` as one kernel-pair launch.
+    """NA over ALL buckets of ``sg`` as one kernel-pair launch, over the
+    tiles ``tile_shape`` reads from ``sg``.
 
     Returns ``(sg.num_targets, H, dh)`` float32 in target order.
     """
+    t_tile, w = tile_shape(sg)
     layout = sg.grouped(t_tile, w)
     n, h, dh = h_proj.shape
     if layout.num_steps == 0:
@@ -171,12 +199,13 @@ def fused_prune_aggregate_grouped(
         layout, prune_k
     )
     use_rel = theta_rel is not None
-    return _grouped_call(
-        h_proj, theta_src, theta_dst,
-        theta_rel if use_rel else None,
-        nbr, msk, ety, row_targets, meta, agg_meta, perm,
-        k_s=k_s, t_tile=t_tile, w=w, slope=slope, use_rel=use_rel,
-    )
+    with tracing.counted("k1_steps", layout.num_steps):
+        return _grouped_call(
+            h_proj, theta_src, theta_dst,
+            theta_rel if use_rel else None,
+            nbr, msk, ety, row_targets, meta, agg_meta, perm,
+            k_s=k_s, t_tile=t_tile, w=w, slope=slope, use_rel=use_rel,
+        )
 
 
 def _sharded_device(sl, prune_k: Optional[int]):
@@ -185,12 +214,11 @@ def _sharded_device(sl, prune_k: Optional[int]):
     SPMD needs every shard to run the same program on same-shaped operands,
     so per-shard stacks are equalized: grid steps pad to one more than the
     max shard's count with filler steps aimed at the reserved pad block
-    (all-masked tiles — the strict-``>`` retention insert admits none of
-    them and the pad block's flush writes zero α and −1 ids), K2 gather
-    steps pad with ``(pad_row, slot 0)`` entries that accumulate that zero
-    α into a row no target's ``perm`` entry reads, and the retention-scratch
-    width ``k_s``
-    is the max across shards (narrower shards park the extra slots at +inf
+    (all-masked tiles — the merge takes none of them and the pad block's
+    flush writes zero α and −1 ids), K2 gather steps pad with ``(pad_row,
+    slot 0)`` entries that accumulate that zero α into a row no target's
+    ``perm`` entry reads, and the retention-scratch width ``k_s`` is the max
+    across shards (narrower shards drop the extra slots at the flush
     exactly like narrow buckets do, so per-target arithmetic — and its bit
     pattern — matches the single-device launch).
     """
@@ -264,8 +292,6 @@ def fused_prune_aggregate_grouped_sharded(
     theta_rel: Optional[jax.Array] = None,  # (R, H)
     prune_k: Optional[int] = None,
     slope: float = 0.2,
-    t_tile: int = T_TILE,
-    w: int = W_TILE,
 ) -> jax.Array:
     """NA over ALL buckets of ``sg``, partitioned across ``mesh[axis]``.
 
@@ -278,6 +304,7 @@ def fused_prune_aggregate_grouped_sharded(
     single-device grouped launch. Returns ``(sg.num_targets, H, dh)`` f32.
     """
     n_sh = mesh.shape[axis]
+    t_tile, w = tile_shape(sg)
     sl = sg.sharded(n_sh, t_tile, w)
     n, h, dh = h_proj.shape
     if sl.num_steps_max == 0:
@@ -289,7 +316,9 @@ def fused_prune_aggregate_grouped_sharded(
     fn = _sharded_fn(mesh, axis, use_rel, k_s, t_tile, w, slope)
     args = (nbr, msk, ety, row_targets, meta, agg_meta, h_proj, theta_src,
             theta_dst) + ((theta_rel,) if use_rel else ())
-    out = fn(*args)
+    # every shard runs the longest shard's steps plus one pad-block step
+    with tracing.counted("k1_steps", n_sh * (sl.num_steps_max + 1)):
+        out = fn(*args)
     # the single all-gather: (S, rows_alloc, H, dh) -> replicated, then one
     # global inverse-permutation gather back to target order
     out = dist.replicate(out, mesh)

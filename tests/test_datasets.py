@@ -154,8 +154,8 @@ def test_cache_miss_then_hit_identical_layouts(tmp_path, small_graph):
     assert list(tmp_path.glob("sgb_*.npz"))
     loaded, st2 = sgb_cache.build_or_load(small_graph, "relation", **kw)
     assert st2 == "hit"
-    tt, w = sgb_cache._tile_constants()
     for a, b in zip(built, loaded):
+        tt, w = sgb_cache._tile_shape(a)
         assert a.name == b.name and a.src_types == b.src_types
         assert a.dst_type == b.dst_type
         assert a.num_edge_types == b.num_edge_types
@@ -235,19 +235,20 @@ def test_cache_hit_upgrades_with_missing_shard_split(tmp_path, small_graph):
     later process precomputed — alongside any splits it already had."""
     kw = dict(max_degree=32, seed=0, bucket_sizes=(4, 8, 16),
               cache_dir=tmp_path)
-    tt, w = sgb_cache._tile_constants()
+    shape = sgb_cache._tile_shape
     _, st = sgb_cache.build_or_load(small_graph, "relation", **kw)
     assert st == "miss"
     up, st = sgb_cache.build_or_load(small_graph, "relation", shards=4, **kw)
     assert st == "hit"
-    assert all((4, tt, w) in sg._sharded for sg in up)
+    assert all((4, *shape(sg)) in sg._sharded for sg in up)
     # a fresh load now carries the 4-way split without rebuilding it
     loaded, _ = sgb_cache.load_sgb(next(tmp_path.glob("sgb_*.npz")))
-    assert all((4, tt, w) in sg._sharded for sg in loaded)
+    assert all((4, *shape(sg)) in sg._sharded for sg in loaded)
     # asking for a second mesh size keeps the first in the entry
     sgb_cache.build_or_load(small_graph, "relation", shards=2, **kw)
     loaded, _ = sgb_cache.load_sgb(next(tmp_path.glob("sgb_*.npz")))
     for sg in loaded:
+        tt, w = shape(sg)
         assert (2, tt, w) in sg._sharded and (4, tt, w) in sg._sharded
         for n in (2, 4):
             fresh = hetgraph.shard_layout(sg.grouped(tt, w), n)

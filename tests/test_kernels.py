@@ -305,6 +305,92 @@ def test_grouped_is_one_pallas_pair(rng):
     assert fpa_kernel.DISPATCH["pallas_calls"] - before == 2
 
 
+def _tie_graph(rng, n):
+    """Hand-built buckets that stress K1's merge: a 256-capacity bucket (two
+    tiles at w=128), a pruned 20-capacity one, under-K ones (5 and 8) that
+    take the bypass, all-masked rows, masked holes, a row repeating one
+    source id, and rows whose candidates tie in rank across tile borders
+    (sources 0-39 share one θ row but not their features)."""
+    from repro.core import hetgraph
+
+    buckets, t0 = [], 0
+    for rows, cap in ((11, 256), (10, 20), (9, 5), (6, 8)):
+        nbr = rng.integers(0, n, size=(rows, cap)).astype(np.int32)
+        msk = rng.random((rows, cap)) < 0.85
+        msk[0] = False  # an all-masked row
+        if cap > 8:
+            nbr[1, ::3] = 7  # one source id, again and again
+            nbr[2] = rng.integers(0, 40, size=cap)  # every rank tied
+            nbr[3, 1::2] = rng.integers(0, 40, size=cap // 2)  # ties among others
+            msk[2] = True
+        buckets.append(hetgraph.DegreeBucket(
+            targets=np.arange(t0, t0 + rows, dtype=np.int32), nbr_idx=nbr,
+            nbr_mask=msk, edge_type=np.zeros_like(nbr),
+        ))
+        t0 += rows
+    return hetgraph.BucketedSemanticGraph("ties", ("x",), "x", t0, tuple(buckets))
+
+
+@pytest.mark.parametrize("w", [8, 32, 128])
+def test_grouped_merge_keeps_first_arrival_top_k(w, rng, monkeypatch):
+    """K1's K-round merge at every tile width keeps ``lax.top_k``'s set with
+    first-arrival ties (the oracle): tied candidates carry different
+    features, so keeping another tied slot moves the output."""
+    from repro.kernels.fused_prune_aggregate import ops
+    from repro.kernels.fused_prune_aggregate.ref import (
+        fused_prune_aggregate_grouped_ref,
+    )
+
+    n, h, dh = 300, 8, 8
+    sg = _tie_graph(rng, n)
+    ts = rng.normal(size=(n, h)).astype(np.float32)
+    ts[:40] = 1.5  # the tied sources outrank most others
+    hp = jnp.asarray(rng.normal(size=(n, h, dh)), jnp.float32)
+    td = jnp.asarray(rng.normal(size=(sg.num_targets, h)), jnp.float32)
+    ts = jnp.asarray(ts)
+    monkeypatch.setattr(ops, "tile_shape", lambda sg: (ops.T_TILE, w))
+    out_k = ops.fused_prune_aggregate_grouped(hp, ts, td, sg, prune_k=8)
+    out_r = fused_prune_aggregate_grouped_ref(hp, ts, td, sg, prune_k=8)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "shapes,want",
+    [
+        # Simple-HGN ACM's author graph: 5,957 rows of capacity 8, 2 of 9
+        (((5957, 8), (2, 9)), 8),
+        # a hub graph: HAN DBLP's APTPA, most rows at capacity 256
+        (((44, 8), (2, 32), (260, 128), (3751, 256)), 128),
+    ],
+    ids=["caps-8-9", "hub-256"],
+)
+def test_tile_width_reads_the_bucket_capacities(shapes, want):
+    from repro.core import hetgraph
+    from repro.kernels.fused_prune_aggregate import ops
+
+    buckets = tuple(
+        hetgraph.DegreeBucket(
+            targets=np.zeros(rows, np.int32), nbr_idx=np.zeros((rows, cap), np.int32),
+            nbr_mask=np.zeros((rows, cap), bool), edge_type=np.zeros((rows, cap), np.int32),
+        )
+        for rows, cap in shapes
+    )
+    sg = hetgraph.BucketedSemanticGraph("g", ("x",), "x", 1, buckets)
+    assert ops.tile_shape(sg) == (ops.T_TILE, want)
+
+
+def test_k2_steps_do_not_depend_on_the_tile_width(rng):
+    """Each row gathers its bucket's min(K, capacity) rows in K2 however
+    wide K1's tiles: K2's step list is the same at every width."""
+    from repro.kernels.fused_prune_aggregate import ops
+
+    sg = _tie_graph(rng, 300)
+    for k in (8, None):
+        metas = [ops.grouped_meta(sg.grouped(ops.T_TILE, w), k)[1] for w in ops.WIDTHS]
+        for m in metas[1:]:
+            np.testing.assert_array_equal(m, metas[0])
+
+
 @pytest.mark.parametrize(
     "b,h,hkv,dh,s,k",
     [(2, 8, 2, 16, 200, 12), (3, 4, 4, 8, 128, 5), (1, 16, 4, 32, 300, 50)],

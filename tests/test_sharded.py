@@ -21,6 +21,7 @@ import pytest
 from repro.core import attention, flows, hetgraph, pipeline
 from repro.core.flows import FlowConfig, run_aggregate_graph
 from repro.kernels.fused_prune_aggregate import kernel as fpa_kernel
+from repro.kernels.fused_prune_aggregate import ops as fpa_ops
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -223,8 +224,8 @@ def test_prepare_presharding_under_mesh():
             "rgat", "imdb", scale=0.04, max_degree=32, seed=0,
             bucket_sizes=(4, 8, 16),
         )
-    key = (4, fpa_kernel.T_TILE, fpa_kernel.W_TILE)
     for sg in task.sgs:
+        key = (4, *fpa_ops.tile_shape(sg))
         assert key in sg._sharded  # built eagerly, not lazily
     # and with no mesh, prepare leaves split building to first dispatch
     task2 = pipeline.prepare(

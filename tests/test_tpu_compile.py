@@ -81,7 +81,7 @@ def test_grouped_pair_compiles_for_v5e(graph, dblp, one_chip, mosaic):
     from repro.kernels.fused_prune_aggregate import ops
 
     sg = dblp.sgs[graph]
-    lay = sg.grouped()
+    lay = sg.grouped(*ops.tile_shape(sg))
     meta, agg_meta, k_s = ops.grouped_meta(lay, K)
     n = dblp.graph.num_nodes["author"]
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
@@ -122,7 +122,7 @@ def test_sharded_body_compiles_for_v5e(dblp, topo, mosaic):
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
     sharded, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     sg = dblp.sgs[0]
-    sl = sg.sharded(4)
+    sl = sg.sharded(4, *ops.tile_shape(sg))
     (nbr, msk, ety, row_targets, _), (meta, agg_meta, k_s) = ops._sharded_device(
         sl, K
     )
@@ -145,6 +145,7 @@ def test_stage_scopes_keep_the_kernel_names_for_v5e(dblp, one_chip, mosaic):
 
     from repro import tracing
     from repro.core import attention, flows
+    from repro.kernels.fused_prune_aggregate import ops
 
     sg = dblp.sgs[0]
     n = dblp.graph.num_nodes["author"]
@@ -155,9 +156,11 @@ def test_stage_scopes_keep_the_kernel_names_for_v5e(dblp, one_chip, mosaic):
         scores = attention.DecomposedScores(ts, td, None)
         return flows.run_aggregate_graph(cfg, h, scores, sg)
 
-    compiled = jax.jit(na).lower(f32(n, H, DH), f32(n, H), f32(sg.num_targets, H)).compile()
+    with tracing.counting() as sites:  # as a session compiles its forward
+        lowered = jax.jit(na).lower(f32(n, H, DH), f32(n, H), f32(sg.num_targets, H))
+    compiled = lowered.compile()
     _assert_mosaic(compiled)
-    tracing.record_scopes(compiled)
+    tracing.record_scopes(compiled, sites)
     calls = re.findall(r"%(\S+) = \S.* custom-call\(.*custom_call_target=\"tpu_custom_call\"",
                        compiled.as_text())
     assert len(calls) == 2
@@ -167,3 +170,6 @@ def test_stage_scopes_keep_the_kernel_names_for_v5e(dblp, one_chip, mosaic):
     assert got == [["jit(fused_prune_aggregate_grouped_pallas)", k,
                     "fused_prune_aggregate_grouped_pallas"] for k in ("k1", "k2")]
     assert all(f"/na.{sg.name}/" in scopes[c] for c in calls)
+    # the K1 steps counter's call-site scope leaves the kernel names alone
+    assert all("/k1_steps.0/" in scopes[c] for c in calls)
+    assert tracing.program_counts()["jit_na"] == {"k1_steps": sg.grouped(*ops.tile_shape(sg)).num_steps}

@@ -186,6 +186,29 @@ def test_compiled_forward_carries_the_stage_scopes():
             assert any(na in c and k in c[c.index(na):] for c in split)
 
 
+@pytest.mark.parametrize("model", ["han", "simple_hgn"])
+def test_compiled_forward_counts_its_k1_steps(model):
+    """The forward program keeps the K1 grid steps its NA call sites
+    launch, each semantic graph's grouped layout at its own tile shape;
+    a call site XLA drops (a last layer's NA into a type no logit reads)
+    counts nothing."""
+    from repro.kernels.fused_prune_aggregate.ops import tile_shape
+
+    task = pipeline.prepare(model, "acm", scale=0.02, max_degree=32, seed=0)
+    before = dict(tracing.program_counts())
+    sess = task.compile(FlowConfig("fused_kernel", prune_k=4))
+    new = {m: c for m, c in tracing.program_counts().items() if before.get(m) is not c}
+    (counts,) = new.values()
+    steps = {sg.name: sg.grouped(*tile_shape(sg)).num_steps for sg in task.sgs}
+    layers = getattr(task.model, "num_layers", 1)
+    label = [sg.name for sg in task.sgs if sg.dst_type == task.graph.label_type]
+    want = sum(steps.values()) + (layers - 1) * sum(steps[n] for n in label)
+    assert counts == {"k1_steps": want} and want > 0
+    with tracing.counting() as outside:
+        sess(task.params)  # dispatching a compiled program counts nothing
+    assert outside == []
+
+
 @pytest.mark.parametrize("name", ["session.forward", "session.query"])
 def test_session_dispatches_are_spans(tmp_path, name):
     task = pipeline.prepare("rgat", "imdb", scale=0.04, max_degree=32, seed=0)
